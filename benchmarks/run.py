@@ -43,6 +43,9 @@ def main(argv=None) -> int:
                     help="where to write BENCH_*.json (default: cwd)")
     args = ap.parse_args(argv)
     os.makedirs(args.out_dir, exist_ok=True)
+    from repro.utils.backend import use_compile_cache
+    use_compile_cache(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
 
     from benchmarks import (roofline, stream_window, table1_llpr,
                             table2_kmeans, table3_terasort, wan_scenario)

@@ -395,7 +395,11 @@ print(f"{{N*4}},{{N*4*n}}")
 
 
 def run_device_level(n_keys: int = 1 << 18) -> dict:
+    """Exchanged-byte counts of the two sorts on 8 virtual CPU devices.
+    The child is pinned to the CPU backend: it counts bytes only, and on
+    a TPU host the chip already belongs to this process."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     out = subprocess.run(

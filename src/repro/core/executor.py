@@ -321,11 +321,12 @@ class _TracedUDF:
     def _call_stack_pieces(self, pieces, n_valids: jax.Array, *,
                            target: int) -> jax.Array:
         """Tuple of per-task 2-D pieces (stage-0 decoded chunks) stacked
-        INSIDE the trace: each piece pads/slices to ``target`` rows, one
-        fused concatenate+reshape forms the [s, target, width] block —
-        no eager per-piece dispatch, mirroring _scatter_dest_segments'
-        in-jit stack rationale."""
-        width = pieces[0].shape[1]
+        INSIDE the trace: each piece pads/slices to ``target`` rows and
+        one ``jnp.stack`` forms the [s, target, width] block — no eager
+        per-piece dispatch, mirroring _scatter_dest_segments' in-jit
+        stack rationale.  Not concatenate+reshape: for uint8 [rows, 100]
+        pieces the TPU compiler takes minutes over that form (PERF.md,
+        "Where the time goes")."""
         blocks = []
         for p in pieces:
             r = p.shape[0]
@@ -334,9 +335,7 @@ class _TracedUDF:
             elif r < target:
                 p = jnp.pad(p, ((0, target - r), (0, 0)))
             blocks.append(p)
-        data3 = jnp.concatenate(blocks, axis=0) \
-            .reshape(len(pieces), target, width)
-        return self._vmapped(data3, n_valids)
+        return self._vmapped(jnp.stack(blocks), n_valids)
 
     def stacked(self, data3: jax.Array, n_valids, target: int) -> jax.Array:
         return self._jit_stacked(data3, n_valids, target=target)
@@ -439,7 +438,8 @@ class ArrayExecutor(_ExecutorBase):
         self.pad_block = pad_block
         self.fused_rounds = fused_rounds
         # the mesh only carries rounds whose slot/worker counts divide
-        # its data axis; others silently use the single-device lowering
+        # its data axis; others use the single-device lowering, and
+        # their shuffle-round span says so (path "fused", not "mesh")
         self.mesh = mesh
         # benchmark honesty knob: block on every shuffled piece before
         # stopping the partition_seconds clock, so deferred-sync timing
@@ -773,6 +773,7 @@ class ArrayExecutor(_ExecutorBase):
         rep.shuffle_rounds += 1
         with self.tracer.span("shuffle-round", track="shuffle",
                               attrs={"backend": "array", "path": "fused",
+                                     "lowering": rd.mode,
                                      "buckets": n}) as sp:
             rep.device_dispatches += rd.dispatches
             synced = jax.device_get(rd.sync_arrays)  # the round's ONE sync

@@ -35,13 +35,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 from repro.core.engine import SphereEngine, SphereReport, SphereSession
 from repro.core.job import SphereJob, SphereStage
@@ -334,8 +329,8 @@ def kmeans_step_jax(points: jax.Array, centroids: jax.Array,
             s, n, i = local(pts, c)
             return (lax.psum(s, axis), lax.psum(n, axis),
                     lax.psum(i, axis))
-        fn = _shard_map(body, mesh=mesh, in_specs=(P(axis), P()),
-                        out_specs=(P(), P(), P()))
+        fn = shard_map(body, mesh=mesh, in_specs=(P(axis), P()),
+                       out_specs=(P(), P(), P()))
         sums, counts, inertia = fn(points, centroids)
     new_c = jnp.where(counts[:, None] > 0,
                       sums / jnp.maximum(counts[:, None], 1), centroids)

@@ -54,10 +54,11 @@ from repro.core.records import (RecordBatch, StackedBatch,  # noqa: F401
                                 scatter_by_ids, uniform_hash_bounds)
 from repro.kernels.bucket_partition import (bucket_dest, bucket_partition,
                                             bucket_scatter)
+from repro.utils.backend import pallas_interpret
 
 
 def _kernel_partition(keys: jax.Array, bounds_u32: np.ndarray, n: int,
-                      *, block_n: int = 1 << 20,
+                      *, block_n: int | None = None,
                       interpret: bool | None = None
                       ) -> Tuple[jax.Array, jax.Array]:
     """bucket_partition over uint32 keys with degenerate-shape handling.
@@ -76,8 +77,7 @@ def _kernel_partition(keys: jax.Array, bounds_u32: np.ndarray, n: int,
         return ids, hist
     nb = len(bounds_u32) + 1
     ids, hist = bucket_partition(keys, jnp.asarray(bounds_u32), n_buckets=nb,
-                                 block_n=min(block_n, nrec),
-                                 interpret=interpret)
+                                 block_n=block_n, interpret=interpret)
     if nb > n:  # clamp overflow buckets, fold their histogram tail
         ids = jnp.minimum(ids, n - 1)
         hist = hist[:n].at[n - 1].add(hist[n:].sum())
@@ -113,7 +113,7 @@ class HashPartitioner:
         return ("hash", self.key_bytes), uniform_hash_bounds(n)
 
     def bucket_ids(self, batch: RecordBatch, n: int, *,
-                   block_n: int = 1 << 20, interpret: bool | None = None
+                   block_n: int | None = None, interpret: bool | None = None
                    ) -> Tuple[jax.Array, jax.Array]:
         keys, bounds = self.kernel_inputs(batch, n)
         return _kernel_partition(keys, bounds, n,
@@ -189,7 +189,7 @@ class RangePartitioner:
                 self.bounds_words(n_words, lengths=need_len))
 
     def bucket_ids(self, batch: RecordBatch, n: int, *,
-                   block_n: int = 1 << 20, interpret: bool | None = None
+                   block_n: int | None = None, interpret: bool | None = None
                    ) -> Tuple[jax.Array, jax.Array]:
         keys, bounds = self.kernel_inputs(batch, n)
         return _kernel_partition(keys, bounds, n,
@@ -208,7 +208,7 @@ class ReducePartitioner:
         return 0
 
     def bucket_ids(self, batch: RecordBatch, n: int, *,
-                   block_n: int = 1 << 20, interpret: bool | None = None
+                   block_n: int | None = None, interpret: bool | None = None
                    ) -> Tuple[jax.Array, jax.Array]:
         nrec = batch.num_records
         ids = jnp.zeros((nrec,), jnp.int32)
@@ -239,7 +239,7 @@ def _host_partition(batch: RecordBatch, partitioner, n: int
 
 
 def partition_batch(batch: RecordBatch, partitioner, n: int, *,
-                    block_n: int = 1 << 20, interpret: bool | None = None
+                    block_n: int | None = None, interpret: bool | None = None
                     ) -> Tuple[jax.Array, jax.Array]:
     """(ids, hist) for a batch under any engine partitioner.
 
@@ -255,7 +255,7 @@ def partition_batch(batch: RecordBatch, partitioner, n: int, *,
 
 
 def shuffle_batch(batch: RecordBatch, partitioner, n: int, *,
-                  block_n: int = 1 << 20, interpret: bool | None = None
+                  block_n: int | None = None, interpret: bool | None = None
                   ) -> List[RecordBatch]:
     """Partition + host-driven scatter: one kernel call, one host
     argsort, n gathers.  The engine uses :func:`scatter_batch` (fully
@@ -379,7 +379,7 @@ class ScatterDispatch:
 
     A pending dispatch is in one of two shapes, per backend:
 
-    * **compiled (TPU/GPU)** — ``out`` holds the bucket-contiguous rows
+    * **compiled (TPU)** — ``out`` holds the bucket-contiguous rows
       (the kernel's device epilogue already moved them); harvest slices
       it by the synced histogram.
     * **host-invert (CPU)** — ``src`` holds the untouched padded block
@@ -482,9 +482,7 @@ def scatter_dispatch(batch: RecordBatch, partitioner, n: int, *,
         return ScatterDispatch(n, pieces=_single_bucket_pieces(batch, n))
     key_spec, bounds = spec
     if interpret is None:
-        # compiled Pallas lowering on real accelerators (TPU Mosaic /
-        # GPU Triton); interpret mode only on CPU
-        interpret = jax.default_backend() not in ("tpu", "gpu")
+        interpret = pallas_interpret()
     data = batch.block(_pow2_rows(nrec, min(pad_block, 1 << 20)))
     if interpret:
         # CPU: stop the jitted call at the destination vector and let
@@ -548,7 +546,7 @@ def scatter_pieces_dispatch(pieces: Sequence[RecordBatch], partitioner,
                                 pad_block=pad_block, block_n=block_n,
                                 interpret=interpret)
     if interpret is None:
-        interpret = jax.default_backend() not in ("tpu", "gpu")
+        interpret = pallas_interpret()
     kernelish = (n > 1 and not isinstance(partitioner, ReducePartitioner)
                  and getattr(partitioner, "scatter_spec", None) is not None)
     nrec = sum(p.num_records for p in pieces)
@@ -715,7 +713,7 @@ class StackedRoundDispatch:
       :func:`_scatter_dest_shard`; ``metas`` holds each shard's
       (dest, hist) and harvest inverts the permutations host-side
       (numpy fancy assignment at memcpy speed).
-    * **vmapped (TPU/GPU)** — ONE :func:`_scatter_stacked` call whose
+    * **vmapped (TPU)** — ONE :func:`_scatter_stacked` call whose
       device epilogue already moved the rows; ``metas`` holds the
       [s, n] per-slot histogram and harvest only computes offsets.
 
@@ -902,7 +900,7 @@ def scatter_round_dispatch(stacked: StackedBatch, partitioner, n: int, *,
         except AttributeError:
             pass                       # __slots__ partitioner: skip cache
     if interpret is None:
-        interpret = jax.default_backend() not in ("tpu", "gpu")
+        interpret = pallas_interpret()
     if lowering is None:
         lowering = "segmented" if interpret else "vmapped"
     W = len(worker_names)

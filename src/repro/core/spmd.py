@@ -13,13 +13,8 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 SENTINEL = jnp.uint32(0xFFFFFFFF)
 
@@ -31,9 +26,9 @@ def sphere_map(udf: Callable, mesh: Mesh, axis: str = "data"):
     along its leading dimension — e.g. the engine's fused stage apply
     passes (stacked data, per-slot valid counts)."""
     def stage(*xs):
-        fn = _shard_map(udf, mesh=mesh,
-                        in_specs=tuple(P(axis) for _ in xs),
-                        out_specs=P(axis))
+        fn = shard_map(udf, mesh=mesh,
+                       in_specs=tuple(P(axis) for _ in xs),
+                       out_specs=P(axis))
         return fn(*xs)
     return stage
 
@@ -45,7 +40,7 @@ def sphere_shuffle(x: jax.Array, bucket_of_shard: Callable, mesh: Mesh,
     def body(buf):
         return lax.all_to_all(buf, axis, split_axis=0, concat_axis=0,
                               tiled=True)
-    fn = _shard_map(body, mesh=mesh, in_specs=P(axis), out_specs=P(axis))
+    fn = shard_map(body, mesh=mesh, in_specs=P(axis), out_specs=P(axis))
     return fn(x)
 
 
@@ -157,12 +152,11 @@ def fused_scatter_round(data: jax.Array, n_valids: jax.Array, bounds,
             .at[sli, posw].set(sr, mode="drop")             # wpd = dropped
         return out, wcount, hist_sb
 
-    # check_rep=False: shard_map has no replication rule for pallas_call
+    # check_vma=False: shard_map has no varying-axes rule for pallas_call
     # (the bucket_partition kernel); every output is explicitly sharded
-    # over ``axis`` anyway, so replication tracking buys nothing here.
-    fn = _shard_map(body, mesh=mesh, in_specs=(P(axis), P(axis)),
-                    out_specs=(P(axis), P(axis), P(axis)),
-                    check_rep=False)
+    # over ``axis`` anyway, so the tracking buys nothing here.
+    fn = shard_map(body, mesh=mesh, in_specs=(P(axis), P(axis)),
+                   out_specs=(P(axis), P(axis), P(axis)), check_vma=False)
     return fn(data, n_valids)
 
 
@@ -216,8 +210,8 @@ def distributed_sort(keys: jax.Array, mesh: Mesh, axis: str = "data",
         valid = jnp.sum((flat != SENTINEL).astype(jnp.int32))
         return out, valid[None]
 
-    fn = _shard_map(body, mesh=mesh, in_specs=P(axis),
-                    out_specs=(P(axis), P(axis)))
+    fn = shard_map(body, mesh=mesh, in_specs=P(axis),
+                   out_specs=(P(axis), P(axis)))
     return fn(keys)
 
 
@@ -234,5 +228,5 @@ def barrier_sort(keys: jax.Array, mesh: Mesh, axis: str = "data"):
         idx = lax.axis_index(axis)
         return lax.dynamic_slice_in_dim(ssorted, idx * m, m)
 
-    fn = _shard_map(body, mesh=mesh, in_specs=P(axis), out_specs=P(axis))
+    fn = shard_map(body, mesh=mesh, in_specs=P(axis), out_specs=P(axis))
     return fn(keys)

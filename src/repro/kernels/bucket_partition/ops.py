@@ -1,16 +1,15 @@
 """jit entry points for the bucket-partition kernels.
 
-Both wrappers pick interpret mode by backend (compiled lowering on real
-accelerators — TPU via Mosaic, GPU via Triton — interpret on CPU) and
+The wrappers pick interpret mode by backend (Mosaic-compiled lowering on
+TPU, interpret on CPU — :func:`repro.utils.backend.pallas_interpret`) and
 choose a backend-appropriate block shape when the caller doesn't:
 
 * **interpret (CPU CI)** — every grid step pays a Python interpreter
   pass, so the default is ONE block covering the whole batch; the
   vectorised jaxpr runs once.
-* **real accelerator** — ``block_n = 2048`` keeps a grid step's live set
-  (keys ``[bn, k]`` uint32, compare state ``[bn, n_bounds]`` bool, and
-  for the scatter the one-hot running count ``[bn, n_out + 1]`` int32)
-  comfortably inside VMEM for 3-word TeraSort keys and <= 64 buckets.
+* **TPU** — ``block_n = 2048`` keeps a grid step's live set (key words
+  ``[k, bn]``, compare state ``[n_bounds, bn]``, see the kernel module's
+  VMEM notes) within 2 MiB of VMEM for TeraSort keys and <= 64 buckets.
 
 ``bucket_scatter`` takes ``n_valid`` as a *dynamic* argument — callers
 pad batches to a fixed shape (e.g. a power-of-two row count) and one
@@ -27,27 +26,25 @@ import jax
 from repro.kernels.bucket_partition.kernel import (bucket_dest_call,
                                                    bucket_partition_call,
                                                    bucket_scatter_call)
+from repro.utils.backend import pallas_interpret
 
-# VMEM-conscious default block rows for real-accelerator lowering (see
-# module docstring); interpret mode uses one whole-batch block instead.
+# VMEM-conscious default block rows for the TPU lowering (see module
+# docstring); interpret mode uses one whole-batch block instead.
 ACCEL_BLOCK_N = 2048
 
 
-def _compiled_backend() -> bool:
-    """True when the default backend gets the compiled Pallas lowering
-    (TPU Mosaic, GPU Triton); CPU stays in interpret mode."""
-    return jax.default_backend() in ("tpu", "gpu")
-
-
 @partial(jax.jit, static_argnames=("n_buckets", "block_n", "interpret"))
-def bucket_partition(keys, bounds, *, n_buckets: int, block_n: int = 2048,
+def bucket_partition(keys, bounds, *, n_buckets: int,
+                     block_n: int | None = None,
                      interpret: bool | None = None):
     """(ids [N] int32, hist [n_buckets] int32) for uint32 key rows.
 
     See :func:`bucket_partition_call` for the comparison contract.
     """
     if interpret is None:
-        interpret = not _compiled_backend()
+        interpret = pallas_interpret()
+    if block_n is None:
+        block_n = keys.shape[0] if interpret else ACCEL_BLOCK_N
     return bucket_partition_call(keys, bounds, n_buckets=n_buckets,
                                  block_n=block_n, interpret=interpret)
 
@@ -65,7 +62,7 @@ def bucket_scatter(data, keys, bounds, n_valid, *, n_buckets: int,
     exist host-side; sync ``hist`` once to learn the bucket boundaries.
     """
     if interpret is None:
-        interpret = not _compiled_backend()
+        interpret = pallas_interpret()
     if block_n is None:
         block_n = data.shape[0] if interpret else ACCEL_BLOCK_N
     return bucket_scatter_call(data, keys, bounds, n_valid,
@@ -87,7 +84,7 @@ def bucket_dest(keys, bounds, n_valid, *, n_buckets: int,
     ~40ns/element, which is why the CPU shuffle path stops here.
     """
     if interpret is None:
-        interpret = not _compiled_backend()
+        interpret = pallas_interpret()
     if block_n is None:
         block_n = keys.shape[0] if interpret else ACCEL_BLOCK_N
     return bucket_dest_call(keys, bounds, n_valid, n_out=n_buckets,

@@ -6,10 +6,7 @@ from functools import partial
 import jax
 
 from repro.kernels.flash_attention.kernel import flash_attention_hm
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+from repro.utils.backend import pallas_interpret
 
 
 @partial(jax.jit, static_argnames=("causal", "window", "block_q", "block_k",
@@ -20,11 +17,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     interpret: bool | None = None) -> jax.Array:
     """q: [B, T, H, D]; k, v: [B, S, K, D] (GQA: H = K * group).
 
-    On non-TPU backends the kernel body runs in interpret mode (CPU
+    On CPU the kernel body runs in interpret mode (CPU
     validation); on TPU it lowers to Mosaic.
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = pallas_interpret()
     B, T, H, D = q.shape
     S, K = k.shape[1], k.shape[2]
     qh = q.transpose(0, 2, 1, 3).reshape(B * H, T, D)

@@ -21,7 +21,10 @@ def _kernel(x_ref, c_ref, ids_ref, d2_ref):
     c = c_ref[...].astype(jnp.float32)          # [K, D]
     xx = jnp.sum(x * x, axis=1, keepdims=True)  # [bn, 1]
     cc = jnp.sum(c * c, axis=1)[None, :]        # [1, K]
+    # HIGHEST: the MXU's default single bf16 pass would round x and c
+    # to 8 mantissa bits and move points near a Voronoi boundary
     xc = jax.lax.dot_general(x, c, (((1,), (1,)), ((), ())),
+                             precision=jax.lax.Precision.HIGHEST,
                              preferred_element_type=jnp.float32)
     d2 = xx - 2.0 * xc + cc                     # [bn, K]
     ids_ref[...] = jnp.argmin(d2, axis=1).astype(jnp.int32)

@@ -7,17 +7,14 @@ import jax.numpy as jnp
 
 from repro.kernels.kmeans_assign.kernel import kmeans_assign_call
 from repro.kernels.kmeans_assign.ref import kmeans_assign_ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+from repro.utils.backend import pallas_interpret
 
 
 @partial(jax.jit, static_argnames=("block_n", "interpret"))
 def kmeans_assign(x, c, *, block_n: int = 1024,
                   interpret: bool | None = None):
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = pallas_interpret()
     return kmeans_assign_call(x, c, block_n=block_n, interpret=interpret)
 
 
@@ -37,7 +34,7 @@ def kmeans_assign_partials(x, c, valid=None, *, block_n: int = 1024,
     Returns (sums [K, D] f32, counts [K] f32).
     """
     if use_kernel is None:
-        use_kernel = _on_tpu()
+        use_kernel = not pallas_interpret()
     if use_kernel:
         ids, _ = kmeans_assign(x, c, block_n=block_n)
     else:
@@ -45,6 +42,7 @@ def kmeans_assign_partials(x, c, valid=None, *, block_n: int = 1024,
     oh = jax.nn.one_hot(ids, c.shape[0], dtype=jnp.float32)
     if valid is not None:
         oh = oh * valid.astype(jnp.float32)[:, None]
-    sums = oh.T @ x.astype(jnp.float32)
+    sums = jnp.dot(oh.T, x.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST)
     counts = oh.sum(0)
     return sums, counts

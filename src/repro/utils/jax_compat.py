@@ -1,45 +1,17 @@
-"""Version-compat shims so the repo runs on jax 0.4.x and >= 0.6.
-
-The newer shard_map API spells partial-manual mode ``axis_names={...},
-check_vma=False``; jax 0.4.x spells the same thing ``auto=<complement>,
-check_rep=False``.  ``shard_map_partial`` translates.
-"""
+"""Partial-manual ``shard_map``: manual over some mesh axes only."""
 from __future__ import annotations
 
-from typing import FrozenSet, Iterable, Optional
+from typing import Iterable, Optional
 
-try:
-    from jax import shard_map as _shard_map  # jax >= 0.6
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def _new_api() -> bool:
-    import inspect
-    try:
-        return "axis_names" in inspect.signature(_shard_map).parameters
-    except (TypeError, ValueError):  # pragma: no cover
-        return False
-
-
-# The 0.4.x ``auto=`` spelling works for simple partial-manual regions but
-# XLA can hit fatal sharding checks on psum-over-subgroup patterns (the
-# podwise train step); callers that need those patterns should gate on this.
-PARTIAL_MANUAL_ROBUST = _new_api()
+from jax import shard_map
 
 
 def shard_map_partial(f, *, mesh, in_specs, out_specs,
                       manual_axes: Optional[Iterable[str]] = None):
-    """shard_map, optionally manual over only ``manual_axes``."""
+    """shard_map, optionally manual over only ``manual_axes`` (the rest
+    stay automatic)."""
     if manual_axes is None:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs)
-    manual: FrozenSet[str] = frozenset(manual_axes)
-    try:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False,
-                          axis_names=manual)
-    except TypeError:  # jax 0.4.x: auto = the axes that stay automatic
-        auto = frozenset(mesh.axis_names) - manual
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False, auto=auto)
+        return shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs)
+    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                     check_vma=False, axis_names=frozenset(manual_axes))

@@ -15,7 +15,7 @@ parity suite holds ``partition_batch`` to:
   of one traced shape.
 
 Everything runs interpret-mode on CPU; ``requires_accelerator`` marks
-the one compiled (non-interpret) case, auto-skipped off-TPU/GPU.
+the compiled (non-interpret) cases, auto-skipped off-TPU.
 """
 import numpy as np
 import pytest
@@ -280,10 +280,10 @@ def test_scatter_pieces_reduce_and_single_bucket_resolve_eagerly():
 
 @pytest.mark.requires_accelerator
 def test_scatter_batch_defaults_to_compiled_on_accelerator():
-    """With interpret unspecified, a GPU/TPU backend must take the
-    compiled Pallas lowering (Triton/Mosaic) — and still match bytes."""
-    from repro.kernels.bucket_partition.ops import _compiled_backend
-    assert _compiled_backend()
+    """With interpret unspecified, a TPU backend must take the compiled
+    Pallas lowering (Mosaic) — and still match bytes."""
+    from repro.utils.backend import pallas_interpret
+    assert not pallas_interpret()
     n, rec, nb = 3000, 16, 6
     blob, records = _random_records(n, rec, seed=8)
     part = range_partitioner(sample_boundaries(records, nb, key_bytes=10))
@@ -321,6 +321,20 @@ def test_kernel_scatter_vs_ref_blocks(block_n):
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref_out))
 
 
+@pytest.mark.parametrize("block_n", [32, 128, 512])
+def test_kernel_rank_scan_width(block_n):
+    """Blocks narrower than, equal to and four times the rank scan's
+    128-lane sub-tile (the last carries counts across sub-tiles) give
+    the oracle's result, over several blocks each."""
+    n, nb = 1000, 6
+    data, keys, bounds = _kernel_case(n, 3, nb, seed=block_n)
+    out, hist = bucket_scatter(data, keys, bounds, n, n_buckets=nb,
+                               block_n=block_n, interpret=True)
+    ref_out, ref_hist = bucket_scatter_ref(data, keys, bounds, nb)
+    np.testing.assert_array_equal(np.asarray(hist), np.asarray(ref_hist))
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref_out))
+
+
 def test_kernel_dynamic_n_valid_reuse():
     """One padded shape, different n_valid values: rows past n_valid
     must scatter to the tail (trash bucket) and never enter the
@@ -342,7 +356,7 @@ def test_kernel_dynamic_n_valid_reuse():
 @pytest.mark.requires_accelerator
 def test_kernel_scatter_compiled():
     """The same oracle check through the compiled (non-interpret) kernel
-    — exercises the real Mosaic/Triton lowering on TPU/GPU."""
+    — exercises the real Mosaic lowering on TPU."""
     n, nb = 5000, 7
     data, keys, bounds = _kernel_case(n, 3, nb, seed=1)
     out, hist = bucket_scatter(data, keys, bounds, n, n_buckets=nb,

@@ -197,7 +197,7 @@ def test_ineligible_rounds_return_none():
 @pytest.mark.requires_accelerator
 def test_vmapped_round_compiles_on_accelerator():
     """The vmapped stacked scatter must lower through the compiled
-    (non-interpret) kernel on a real TPU/GPU backend."""
+    (non-interpret) kernel on a real TPU backend."""
     rec, n = 16, 4
     slots = _ragged_round([7, 5, 0, 12], rec, seed=5)
     slot_workers = np.arange(4)

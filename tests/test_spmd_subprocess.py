@@ -7,23 +7,14 @@ import subprocess
 import sys
 import textwrap
 
-import pytest
-
-from repro.utils import jax_compat
 
 ROOT = os.path.join(os.path.dirname(__file__), "..")
 
-# version-compat preamble available to every subprocess snippet:
-# mk_mesh(shape, axes) and use_mesh(mesh) work on jax 0.4.x and >= 0.5
+# preamble available to every subprocess snippet
 _PREAMBLE = """
 import jax
-from repro.launch.mesh import make_mesh_compat as mk_mesh
-try:
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
-def use_mesh(mesh):
-    return jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
+from jax import shard_map
+from repro.launch.mesh import make_auto_mesh as mk_mesh
 """
 
 
@@ -106,9 +97,6 @@ def test_fused_scatter_round_multidevice_matches_host():
     assert "OK" in out
 
 
-@pytest.mark.skipif(not jax_compat.PARTIAL_MANUAL_ROBUST,
-                    reason="podwise psum-over-pod inside a partial-manual "
-                           "region is fatal in XLA for jax 0.4.x shard_map")
 def test_podwise_mode_matches_pjit():
     """Manual-pod train step == plain pjit step (no compression)."""
     out = run_py("""
@@ -133,7 +121,7 @@ def test_podwise_mode_matches_pjit():
             pcfg = ParallelConfig(mesh=mesh, multi_pod=True, mode=mode,
                                   remat='none')
             step = make_train_step(cfg, pcfg, ocfg, lr)
-            with use_mesh(mesh):
+            with jax.set_mesh(mesh):
                 p2, o2, m = jax.jit(step)(params, opt, batch)
             outs[mode] = (jax.device_get(p2), float(m['loss']))
         a, b = outs['pjit'], outs['podwise']
@@ -199,7 +187,7 @@ def test_sharded_train_step_matches_single_device():
             mesh = mk_mesh((2, 2), ('data', 'model'))
         pcfg = ParallelConfig(mesh=mesh, remat='none')
         step = make_train_step(cfg, pcfg, ocfg, lr)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             p2, o2, m = jax.jit(step)(params, opt, batch)
         print('LOSS', float(m['loss']))
     """
