@@ -80,10 +80,11 @@ class SphereEngine:
         # observability plane: a recording Tracer threads spans through
         # every planner/executor/stream this engine builds and turns the
         # master's bus events into timeline instants; the default
-        # NULL_TRACER records nothing and costs nothing.  The metrics
-        # registry mirrors every report the engine's runs write.
+        # NULL_TRACER records nothing and costs nothing.  A metrics
+        # registry, when given, mirrors every report the engine's runs
+        # write; without one no report is bound.
         self.tracer = tracer or NULL_TRACER
-        self.metrics = metrics if metrics is not None else MetricsRegistry()
+        self.metrics = metrics
         if self.tracer.enabled:
             self.master.tracer = self.tracer
             self.tracer.attach_bus(master.events)

@@ -25,7 +25,9 @@ workers and simulated time agrees across backends for the same job.
 """
 from __future__ import annotations
 
+import functools
 import queue
+import re
 import threading
 from dataclasses import dataclass
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
@@ -99,11 +101,12 @@ class _ExecutorBase:
             return self._chunk_cache[key]
         with self.tracer.span("fetch-chunk", track="fetch",
                               attrs={"key": key}) as sp:
-            blob = self._fetch_chunk(key, rep)
+            with self.tracer.span("sector-read", track="fetch"):
+                blob = self._fetch_chunk(key, rep)
             if blob is None:
                 sp.set_attrs(lost=True)
                 return None
-            decoded = self._decode_chunk(job, blob)
+            decoded = self._decode_chunk(job, blob, "fetch")
         if self._chunk_cache is not None:
             self._chunk_cache[key] = decoded
         return decoded
@@ -123,7 +126,9 @@ class _ExecutorBase:
         attempt one, so ``rep.retried`` and repair behaviour are
         bit-identical with prefetching off (and across depths).
         ``decoded_input`` is None when every replica of a chunk is gone
-        (the caller skips the task)."""
+        (the caller skips the task).  The consumer's wait on the queue is
+        the ``prefetch-wait`` span: time the data plane stood waiting on
+        Sector, where ``fetch-chunk`` is fetch work that overlaps it."""
         if not self.prefetch or len(tasks) <= 1:
             for t in tasks:
                 yield t, self._stage0_input(job, t.key, rep)
@@ -141,8 +146,10 @@ class _ExecutorBase:
                 try:
                     with self.tracer.span("fetch-chunk", track="prefetch",
                                           attrs={"key": t.key}):
-                        payload = self._decode_chunk(
-                            job, self.client.read_chunk(t.key))
+                        with self.tracer.span("sector-read",
+                                              track="prefetch"):
+                            blob = self.client.read_chunk(t.key)
+                        payload = self._decode_chunk(job, blob, "prefetch")
                     q.put(("ok", payload))
                 except (IOError, ServerDown):
                     q.put(("retry", None))
@@ -154,7 +161,8 @@ class _ExecutorBase:
                               name="sphere-prefetch")
         th.start()
         for t in tasks:
-            kind, payload = q.get()
+            with self.tracer.span("prefetch-wait", track="fetch"):
+                kind, payload = q.get()
             if kind == "ok":
                 if self._chunk_cache is not None:
                     self._chunk_cache[t.key] = payload
@@ -175,7 +183,8 @@ class BytesExecutor(_ExecutorBase):
     def part_sizes(self, parts) -> Dict[str, int]:
         return {w: sum(len(r) for r in parts[w]) for w in self.workers}
 
-    def _decode_chunk(self, job: SphereJob, blob: bytes) -> List[bytes]:
+    def _decode_chunk(self, job: SphereJob, blob: bytes, track: str
+                      ) -> List[bytes]:
         return job.split_records(blob)
 
     def run_stage(self, job: SphereJob, stage: SphereStage, plan: StagePlan,
@@ -233,7 +242,20 @@ class BytesExecutor(_ExecutorBase):
             parts[w] = out[w]
 
     def outputs(self, parts) -> List[bytes]:
-        return [b"".join(parts[w]) for w in self.workers if parts[w]]
+        with self.tracer.span("materialise", track="output"):
+            return [b"".join(parts[w]) for w in self.workers if parts[w]]
+
+
+def _stage_fn(fn, stage: str, kind: str):
+    """``fn`` under the name ``stage_<stage>_<kind>``, which jit gives
+    the compiled module; the signature stays ``fn``'s, for
+    ``static_argnames``."""
+    @functools.wraps(fn)
+    def named(*args, **kwargs):
+        return fn(*args, **kwargs)
+    safe = re.sub(r"\W", "_", stage)
+    named.__name__ = named.__qualname__ = f"stage_{safe}_{kind}"
+    return named
 
 
 class _TracedUDF:
@@ -261,15 +283,21 @@ class _TracedUDF:
         self.pad_value = pad_value
         self.mesh = mesh
         self.traces = 0
-        self._jit = jax.jit(self._call_masked if masked else
-                            self._call_padded)
+        # every entry point compiles to a module named after the stage
+        # (``jit_stage_sort_pieces``), so the device trace attributes
+        # each op to its stage
+        self._jit = jax.jit(
+            _stage_fn(self._call_masked, name, "masked") if masked
+            else _stage_fn(self._call_padded, name, "padded"))
         # fused-round entry points: the whole stage as ONE vmapped call
         # over the stacked slot axis (``target`` static so one trace
         # serves every round at the stage's block shape)
-        self._jit_stacked = jax.jit(self._call_stacked,
-                                    static_argnames=("target",))
-        self._jit_stack_pieces = jax.jit(self._call_stack_pieces,
-                                         static_argnames=("target",))
+        self._jit_stacked = jax.jit(
+            _stage_fn(self._call_stacked, name, "stacked"),
+            static_argnames=("target",))
+        self._jit_stack_pieces = jax.jit(
+            _stage_fn(self._call_stack_pieces, name, "pieces"),
+            static_argnames=("target",))
 
     def _check(self, out) -> jax.Array:
         if not isinstance(out, RecordBatch):
@@ -455,8 +483,13 @@ class ArrayExecutor(_ExecutorBase):
         return {w: (parts[w].nbytes if parts[w] is not None else 0)
                 for w in self.workers}
 
-    def _decode_chunk(self, job: SphereJob, blob: bytes) -> RecordBatch:
-        return job.split_batch(blob)
+    def _decode_chunk(self, job: SphereJob, blob: bytes, track: str
+                      ) -> RecordBatch:
+        # the split is a zero-copy view: what takes the time is the put,
+        # whose host side this span holds; its transfer runs on after it
+        with self.tracer.span("h2d-put", track=track,
+                              attrs={"bytes": len(blob)}):
+            return job.split_batch(blob)
 
     # --------------------------------------------------------- UDF apply
     def _traced_for(self, stage: SphereStage, udf, *,
@@ -489,10 +522,7 @@ class ArrayExecutor(_ExecutorBase):
         returned whole — reduction outputs have no padding rows to
         slice off."""
         traced = self._traced_for(stage, stage.masked_udf, masked=True)
-        with self.tracer.span("dispatch:udf", track="dispatch",
-                              attrs={"stage": stage.name, "rows": target}):
-            out = traced(batch.block(target), batch.num_records,
-                         stage.params)
+        out = traced(batch.block(target), batch.num_records, stage.params)
         rep.device_dispatches += 1
         self._note_traces(stage, traced, rep)
         return RecordBatch(out)
@@ -506,9 +536,7 @@ class ArrayExecutor(_ExecutorBase):
         there."""
         traced = self._traced_for(stage, stage.batch_udf)
         n = batch.num_records
-        with self.tracer.span("dispatch:udf", track="dispatch",
-                              attrs={"stage": stage.name, "rows": target}):
-            out = traced(batch.block(target), n)
+        out = traced(batch.block(target), n)
         rep.device_dispatches += 1
         self._note_traces(stage, traced, rep)
         if out.shape[0] != target:
@@ -584,10 +612,7 @@ class ArrayExecutor(_ExecutorBase):
                 # legacy/compat path: bytes-udf decode, per-shape tracing
                 # (shape-polymorphic UDFs see exact batches, never junk
                 # padding rows)
-                with self.tracer.span("dispatch:udf", track="dispatch",
-                                      attrs={"stage": stage.name,
-                                             "rows": batch.num_records}):
-                    out[dst].append(stage.apply_batch(batch.compact()))
+                out[dst].append(stage.apply_batch(batch.compact()))
                 rep.device_dispatches += 1
         return out
 
@@ -649,13 +674,9 @@ class ArrayExecutor(_ExecutorBase):
             if stacked is not None \
                     and stacked.n_slots == self._mesh_slots(stacked.n_slots):
                 # steady state: the resident stack IS the stage input
-                with self.tracer.span("dispatch:udf-fused", track="dispatch",
-                                      attrs={"stage": stage.name,
-                                             "slots": stacked.n_slots,
-                                             "rows": target}):
-                    out = traced.stacked(
-                        stacked.data,
-                        jnp.asarray(stacked.n_valid, jnp.int32), target)
+                out = traced.stacked(stacked.data,
+                                     jnp.asarray(stacked.n_valid, jnp.int32),
+                                     target)
                 rep.device_dispatches += 1
                 self._note_traces(stage, traced, rep)
                 self._check_stacked(stage, out, stacked.n_slots, target)
@@ -691,12 +712,8 @@ class ArrayExecutor(_ExecutorBase):
                                       np.zeros(pad_slots, np.int32)])
             slot_workers = np.concatenate(
                 [slot_workers, np.zeros(pad_slots, np.int64)])
-        with self.tracer.span("dispatch:udf-fused", track="dispatch",
-                              attrs={"stage": stage.name,
-                                     "slots": len(pieces), "rows": target}):
-            out = traced.stack_pieces(pieces,
-                                      jnp.asarray(n_valid, jnp.int32),
-                                      target)
+        out = traced.stack_pieces(pieces, jnp.asarray(n_valid, jnp.int32),
+                                  target)
         rep.device_dispatches += 1
         self._note_traces(stage, traced, rep)
         self._check_stacked(stage, out, len(pieces), target)
@@ -948,9 +965,27 @@ class ArrayExecutor(_ExecutorBase):
             parts[w] = RecordBatch.concat(out[w]) if out[w] else None
 
     def outputs(self, parts) -> List[bytes]:
-        # the ONLY host materialisation of record data after stage 0
-        return [_as_batch(parts[w]).to_bytes() for w in self.workers
-                if parts[w] is not None and parts[w].num_records]
+        """The ONLY host materialisation of record data after stage 0.
+        Per partition, ``output-wait`` holds the wait for the device
+        work behind it (the wait ``np.asarray`` would make, made first)
+        and ``d2h`` the copy to the host: ``d2h-transfer`` the device
+        array to a host one, ``d2h-tobytes`` its valid rows to bytes."""
+        out = []
+        with self.tracer.span("materialise", track="output"):
+            for w in self.workers:
+                if parts[w] is None or not parts[w].num_records:
+                    continue
+                batch = _as_batch(parts[w])
+                with self.tracer.span("output-wait", track="output"):
+                    jax.block_until_ready(batch.data)
+                with self.tracer.span("d2h", track="output") as sp:
+                    with self.tracer.span("d2h-transfer", track="output"):
+                        host = np.asarray(batch.data)
+                    with self.tracer.span("d2h-tobytes", track="output"):
+                        # valid rows only: padding never leaks out
+                        out.append(host[:batch.num_records].tobytes())
+                    sp.set_attrs(bytes=len(out[-1]))
+        return out
 
 
 def make_executor(backend: str, client, workers: Sequence[str], *,

@@ -11,8 +11,11 @@ summed away into end-of-job aggregates.
 Two clock domains coexist, and every span/instant belongs to exactly one:
 
 * ``wall``  — real host seconds (``time.perf_counter`` relative to the
-  tracer's construction).  The data plane lives here: chunk fetches,
-  UDF dispatches, shuffle rounds, host-sync markers.
+  tracer's construction).  The data plane lives here: chunk reads and
+  device puts, shuffle rounds, host-sync markers, output copy-out, and
+  JAX's own compile path (``jit-trace`` / ``jit-lower`` /
+  ``jit-compile`` / ``jit-cache-load``, taken from ``jax.monitoring``
+  events while a recording tracer lives).
 * ``sim``   — the engine's simulated seconds.  The control plane lives
   here: per-task execution spans on ``worker:*`` tracks, transfer
   reservations on ``link:*`` tracks, Sector bus events.
@@ -37,9 +40,12 @@ import itertools
 import json
 import threading
 import time
+import weakref
 from typing import Dict, Hashable, List, Optional, Tuple
 
-__all__ = ["Span", "Tracer", "NullTracer", "NULL_TRACER"]
+from jax import monitoring
+
+__all__ = ["Span", "Tracer", "NullTracer", "NULL_TRACER", "COMPILE_SPANS"]
 
 WALL = "wall"
 SIM = "sim"
@@ -49,6 +55,18 @@ _CLOCKS = (WALL, SIM)
 # pid as its own process group with an independent time axis origin)
 _PID = {SIM: 1, WALL: 2}
 _PID_NAME = {SIM: "sim-clock", WALL: "wall-clock"}
+
+# JAX's compile-path events and the wall span each becomes.  The three
+# time-span events carry ``time.time()`` bounds and the function's name;
+# the persistent-cache read reports only its duration, at its end, from
+# inside the ``jit-compile`` that asked for it.
+_JIT_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jit-trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit-lower",
+    "/jax/core/compile/backend_compile_duration": "jit-compile",
+}
+_CACHE_LOAD = "/jax/compilation_cache/cache_retrieval_time_sec"
+COMPILE_SPANS = (*_JIT_SPANS.values(), "jit-cache-load")
 
 
 class Span:
@@ -163,11 +181,15 @@ class Tracer:
 
     def __init__(self):
         self._epoch = time.perf_counter()
+        # JAX stamps compile events with time.time(): one offset, taken
+        # here, puts them on this tracer's clock
+        self._unix_epoch = time.time()
         self._events: List[Span] = []
         self._ids = itertools.count(1)
         self._lock = threading.Lock()
         self._tls = threading.local()
         self._open = 0
+        _COMPILE_EVENTS.watch(self)
 
     # ---------------------------------------------------------- recording
     def _now(self) -> float:
@@ -195,6 +217,7 @@ class Tracer:
         return sp
 
     def _close(self, sp: Span) -> None:
+        sp._tracer = None                  # no span -> tracer cycle
         stack = self._stack()
         if stack and stack[-1] == sp.span_id:
             stack.pop()
@@ -234,6 +257,32 @@ class Tracer:
         with self._lock:
             self._events.append(sp)
         return sp
+
+    # ------------------------------------------------------- compile path
+    def _busy(self) -> bool:
+        """Whether a span of this tracer is open on the calling thread."""
+        return bool(getattr(self._tls, "stack", None))
+
+    def _jit_span(self, name: str, start: float, end: float,
+                  fun_name) -> None:
+        """One of JAX's compile events as a wall span, a child of the
+        span open on the compiling thread; a ``jit-compile`` names the
+        cache loads inside it."""
+        self.add_span(name, track="compile", clock=WALL,
+                      t0=start - self._unix_epoch, t1=end - self._unix_epoch,
+                      attrs={"fun_name": fun_name})
+        if name == "jit-compile":
+            for load in getattr(self._tls, "loads", ()):
+                load.set_attrs(fun_name=fun_name)
+            self._tls.loads = []
+
+    def _cache_load(self, seconds: float) -> None:
+        t1 = self._now()
+        sp = self.add_span("jit-cache-load", track="compile", clock=WALL,
+                           t0=t1 - seconds, t1=t1)
+        if not hasattr(self._tls, "loads"):
+            self._tls.loads = []
+        self._tls.loads.append(sp)
 
     # ----------------------------------------------------------- event bus
     def attach_bus(self, bus, *, replay: bool = True):
@@ -339,6 +388,71 @@ class Tracer:
             with open(path, "w") as f:
                 json.dump(doc, f, indent=1, default=repr)
         return doc
+
+
+class _CompileEvents:
+    """Forwards JAX's compile events to every live recording
+    :class:`Tracer` with a span open on the compiling thread, so a
+    tracer records what its own work compiled and nothing of another
+    engine's.  Its two ``jax.monitoring`` listeners exist only
+    while some Tracer does: the first Tracer registers them and the
+    collection of the last one removes them, so a process that only
+    ever uses :data:`NULL_TRACER` never pays a listener call."""
+
+    def __init__(self):
+        # re-entrant: a Tracer collected while this object holds the
+        # lock calls back into _forget on the same thread
+        self._lock = threading.RLock()
+        self._tracers: set = set()       # weakrefs to live Tracers
+        self._registered = False
+
+    def watch(self, tracer: "Tracer") -> None:
+        with self._lock:
+            self._tracers.add(weakref.ref(tracer, self._forget))
+            self._sync()
+
+    def _forget(self, ref) -> None:
+        with self._lock:
+            self._tracers.discard(ref)
+            self._sync()
+
+    def _sync(self) -> None:
+        want = bool(self._tracers)
+        if want == self._registered:
+            return
+        self._registered = want
+        if want:
+            monitoring.register_event_time_span_listener(self._on_span)
+            monitoring.register_event_duration_secs_listener(
+                self._on_duration)
+        else:
+            monitoring.unregister_event_time_span_listener(self._on_span)
+            monitoring.unregister_event_duration_listener(self._on_duration)
+
+    @property
+    def listening(self) -> bool:
+        return self._registered
+
+    def _live(self) -> List["Tracer"]:
+        with self._lock:
+            refs = list(self._tracers)
+        return [t for t in (r() for r in refs)
+                if t is not None and t._busy()]
+
+    def _on_span(self, event: str, start: float, end: float,
+                 **kwargs) -> None:
+        name = _JIT_SPANS.get(event)
+        if name is not None:
+            for t in self._live():
+                t._jit_span(name, start, end, kwargs.get("fun_name"))
+
+    def _on_duration(self, event: str, seconds: float, **_kwargs) -> None:
+        if event == _CACHE_LOAD:
+            for t in self._live():
+                t._cache_load(seconds)
+
+
+_COMPILE_EVENTS = _CompileEvents()
 
 
 def link_track(key: Hashable) -> str:

@@ -13,6 +13,8 @@ The topology is described inside a module fixture (never at import): only
 one process may load the TPU library, and under several test workers
 only the worker that runs this file does.
 """
+import os
+import sys
 from functools import partial
 
 import numpy as np
@@ -23,6 +25,8 @@ import jax.numpy as jnp
 from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
+from repro.core.executor import _TracedUDF
+from repro.core.kmeans import make_kmeans_stages
 from repro.core.shuffle import _scatter_stacked
 from repro.core.spmd import fused_scatter_round
 from repro.kernels.bucket_partition.kernel import (bucket_dest_call,
@@ -30,6 +34,9 @@ from repro.kernels.bucket_partition.kernel import (bucket_dest_call,
                                                    bucket_scatter_call)
 from repro.kernels.bucket_partition.ops import ACCEL_BLOCK_N
 from repro.kernels.kmeans_assign.kernel import kmeans_assign_call
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))  # the checkout root, for ``bench``
 
 ROWS = 1 << 16                  # rows per compiled batch (scale, not width)
 RECORD = 100                    # TeraSort record bytes
@@ -124,3 +131,62 @@ def test_mesh_round_compiles(topo):
                                  sharding=sharded),
         jax.ShapeDtypeStruct((8,), jnp.int32, sharding=sharded))
     assert "all-to-all" in compiled.as_text()
+
+
+def _module_and_ops(compiled):
+    """The compiled module's name and its instructions' HLO text, as the
+    device trace names them (``%op = ...``)."""
+    lines = compiled.as_text().splitlines()
+    module = lines[0].split()[1].rstrip(",")
+    ops = [ln.strip().removeprefix("ROOT ") for ln in lines]
+    return module, [op for op in ops if op.startswith("%")]
+
+
+def _kernel_regex(metric):
+    from bench.registry import load_module
+    return load_module("metrics", metric).KERNEL
+
+
+def test_assign_stage_module_and_kernel_name(one_chip, monkeypatch):
+    """The k-means assign stage compiles to a module named after the
+    stage, and its Pallas call still matches the roofline reader."""
+    import re
+    from repro.kernels.kmeans_assign import ops
+    monkeypatch.setattr(ops, "pallas_interpret", lambda: False)
+    assign = make_kmeans_stages(20, 10, "array")[0]
+    traced = _TracedUDF(assign.name, assign.masked_udf, masked=True)
+    compiled = traced._jit.lower(one_chip((ROWS, 80), jnp.uint8),
+                                 one_chip((), jnp.int32),
+                                 one_chip((10, 20), jnp.float32)).compile()
+    module, ops_text = _module_and_ops(compiled)
+    assert module == "jit_stage_assign_masked"
+    rx = re.compile(_kernel_regex("kmeans_assign_roofline"))
+    assert [op for op in ops_text if rx.search(op)]
+
+
+def test_stacked_round_kernel_matches_the_roofline_reader(one_chip):
+    import re
+    compiled = _compile(partial(_scatter_stacked, n_buckets=6,
+                                key_spec=("range", 10, 3, None),
+                                block_n=None, interpret=False),
+                        one_chip((2, 4096, RECORD), jnp.uint8),
+                        one_chip((5, 3), jnp.uint32),
+                        one_chip((2,), jnp.int32))
+    _, ops_text = _module_and_ops(compiled)
+    rx = re.compile(_kernel_regex("bucket_scatter_roofline"))
+    assert [op for op in ops_text if rx.search(op)]
+
+
+@pytest.mark.parametrize("stage", ["partition", "sort"])
+def test_terasort_stage_modules_carry_the_stage_name(one_chip, stage):
+    """Both TeraSort stages run through the stacked-pieces entry point;
+    their modules differ by the stage's name, which the device trace
+    keeps (``sort_device_s`` reads ``jit_stage_sort_*``)."""
+    from repro.core.shuffle import terasort_stages
+    st = {s.name: s for s in terasort_stages([], "array", 1)}[stage]
+    traced = _TracedUDF(st.name, st.batch_udf, pad_value=st.pad_value)
+    piece = one_chip((256, RECORD), jnp.uint8)
+    compiled = traced._jit_stack_pieces.lower(
+        (piece,), one_chip((1,), jnp.int32), target=256).compile()
+    module, _ = _module_and_ops(compiled)
+    assert module == f"jit_stage_{stage}_pieces"

@@ -7,14 +7,20 @@ registry's instrument semantics, and the two reconciliation guarantees:
 into, and the bytes and array backends emit identical span *counts* for
 every shared (non-device) span name on the same job.
 """
+import gc
 import threading
 
 import numpy as np
 import pytest
 
+import jax
+import jax.numpy as jnp
+
 from conftest import make_cloud
 from repro.core import (MetricsRegistry, NULL_TRACER, SphereEngine,
                         SphereJob, Tracer)
+from repro.core import trace as trace_mod
+from repro.core.kmeans import encode_points, kmeans_sphere
 from repro.core.planner import _MIRRORED_COUNTERS
 from repro.core.shuffle import sample_boundaries, terasort_stages
 from repro.core.trace import NullTracer, link_track
@@ -190,7 +196,7 @@ def _gen_records(n, seed=0):
     return np.concatenate([keys, payload], axis=1).tobytes()
 
 
-def _run_terasort(tmp_path, backend, tracer=None, n=1500):
+def _run_terasort(tmp_path, backend, tracer=None, n=1500, prefetch=True):
     master, _, client = make_cloud(tmp_path / backend,
                                    chunk_size=500 * RECORD)
     data = _gen_records(n)
@@ -198,7 +204,8 @@ def _run_terasort(tmp_path, backend, tracer=None, n=1500):
     recs = [data[i:i + RECORD] for i in range(0, 200 * RECORD, RECORD)]
     bounds = sample_boundaries(recs, 4, key_bytes=KEY)
     metrics = MetricsRegistry()
-    eng = SphereEngine(master, client, tracer=tracer, metrics=metrics)
+    eng = SphereEngine(master, client, tracer=tracer, metrics=metrics,
+                       prefetch=prefetch)
     job = SphereJob("tsort", "tera",
                     terasort_stages(bounds, backend, 4, key_bytes=KEY),
                     record_size=RECORD, backend=backend)
@@ -231,11 +238,17 @@ def test_report_equals_registry_array(tmp_path):
                              **labels) == traces
 
 
+ARRAY_ONLY = {"host-sync", "h2d-put", "output-wait", "d2h", "d2h-transfer",
+              "d2h-tobytes"}
+
+
 def _shared_span_counts(tracer):
     """Span counts for names both backends emit: device-only names
-    (``dispatch:*`` UDF dispatches, ``host-sync`` markers) excluded."""
+    (``host-sync`` markers, the stage-0 device put, the output wait and
+    copy-out) and compile spans (what the process had not yet compiled)
+    excluded."""
     return {name: c for name, c in tracer.counts_by_name().items()
-            if not name.startswith("dispatch:") and name != "host-sync"}
+            if name not in ARRAY_ONLY and not name.startswith("jit-")}
 
 
 def test_span_count_parity_bytes_vs_array(tmp_path):
@@ -294,3 +307,136 @@ def test_master_instants_and_repair_span(tmp_path):
     assert "repaired" in rep_span.attrs
     assert tracer.count("master:repair-plan") >= 1
     assert daemon.event_repairs == rep_span.attrs["repaired"]
+
+
+# ------------------------- spans inside the job -----------------------------
+
+def _children(tracer, parent, name):
+    return [e for e in tracer.snapshot()
+            if e.name == name and e.parent_id == parent.span_id]
+
+
+@pytest.mark.parametrize("backend", ["bytes", "array"])
+def test_one_materialise_per_job_run(tmp_path, backend):
+    out, _, _, tracer = _run_terasort(tmp_path, backend, Tracer())
+    assert tracer.count("materialise") == 1
+    if backend == "bytes":
+        assert tracer.count("d2h") == tracer.count("output-wait") == 0
+        return
+    (mat,) = [e for e in tracer.snapshot() if e.name == "materialise"]
+    d2h = _children(tracer, mat, "d2h")
+    assert len(d2h) == len(_children(tracer, mat, "output-wait")) \
+        == len(out)
+    # the copy-out's bytes are the output's, padding left behind
+    assert sum(e.attrs["bytes"] for e in d2h) == sum(map(len, out))
+    # each copy-out is the transfer to a host array, then its bytes
+    for e in d2h:
+        (xfer,) = _children(tracer, e, "d2h-transfer")
+        (pack,) = _children(tracer, e, "d2h-tobytes")
+        assert e.t0 <= xfer.t0 <= xfer.t1 <= pack.t0 <= pack.t1 <= e.t1
+
+
+@pytest.mark.parametrize("prefetch", [True, False])
+@pytest.mark.parametrize("backend", ["bytes", "array"])
+def test_fetch_chunk_splits_into_read_and_put(tmp_path, backend, prefetch):
+    _, rep, _, tracer = _run_terasort(tmp_path, backend, Tracer(),
+                                      prefetch=prefetch)
+    fetches = [e for e in tracer.snapshot() if e.name == "fetch-chunk"]
+    assert len(fetches) == 3 and rep.retried == 0
+    assert {e.track for e in fetches} == \
+        {"prefetch" if prefetch else "fetch"}
+    for f in fetches:
+        (read,) = _children(tracer, f, "sector-read")
+        assert read.track == f.track
+        puts = _children(tracer, f, "h2d-put")
+        if backend == "bytes":
+            assert puts == []
+        else:
+            (put,) = puts
+            assert put.track == f.track and put.attrs["bytes"] == 500 * RECORD
+            assert read.t1 <= put.t0
+    # the consumer waits once per prefetched stage-0 task
+    assert tracer.count("prefetch-wait") == (3 if prefetch else 0)
+
+
+def test_no_dispatch_spans(tmp_path):
+    _, _, _, tracer = _run_terasort(tmp_path, "array", Tracer())
+    assert not [n for n in tracer.counts_by_name()
+                if n.startswith("dispatch:")]
+
+
+def test_engine_binds_no_registry_unless_given(tmp_path):
+    master, _, client = make_cloud(tmp_path, chunk_size=500 * RECORD)
+    client.upload("tera", _gen_records(600))
+    eng = SphereEngine(master, client)
+    assert eng.metrics is None
+    job = SphereJob("tsort", "tera", terasort_stages([], "bytes", 1),
+                    record_size=RECORD)
+    _, rep = eng.run(job)
+    assert rep.__dict__.get("_metrics") is None
+    assert rep.metric_labels == {}
+
+
+def _double(x):
+    return x * 2 + 1
+
+
+def test_jit_spans_on_first_call_only():
+    tracer = Tracer()
+    f = jax.jit(_double)
+    x = jnp.arange(11, dtype=jnp.float32)
+    with tracer.span("first") as first:
+        f(x)
+    traces = [e for e in tracer.snapshot() if e.name == "jit-trace"]
+    assert [e.attrs["fun_name"] for e in traces].count("_double") == 1
+    lowers = [e for e in tracer.snapshot() if e.name == "jit-lower"]
+    assert any("_double" in e.attrs["fun_name"] for e in lowers)
+    for e in traces + lowers:
+        assert e.clock == "wall" and e.parent_id == first.span_id
+        assert first.t0 <= e.t0 <= e.t1 <= first.t1 + 1e-3
+    before = tracer.count()
+    with tracer.span("second"):
+        f(x)
+    assert tracer.count() == before + 1      # the "second" span alone
+    # outside any of its spans a tracer records no compile
+    jax.jit(_double)(jnp.arange(13, dtype=jnp.float32))
+    assert tracer.count() == before + 1
+
+
+def _listening():
+    """Whether the compile listeners are registered with JAX, once every
+    unreachable Tracer has been collected."""
+    from jax._src import monitoring
+
+    gc.collect()
+    watch = trace_mod._COMPILE_EVENTS
+    spans = watch._on_span in monitoring.get_event_time_span_listeners()
+    durations = watch._on_duration in monitoring.get_event_duration_listeners()
+    assert spans == durations == watch.listening
+    return spans
+
+
+def test_compile_listener_lives_with_the_tracers():
+    assert not _listening()
+    a, b = Tracer(), Tracer()
+    assert _listening()
+    del a
+    assert _listening()
+    del b
+    assert not _listening()
+    # many tracers, built and dropped, leave nothing behind
+    for _ in range(50):
+        Tracer()
+    assert not _listening()
+
+
+def test_null_tracer_records_nothing_and_listens_to_nothing(tmp_path):
+    assert not _listening()
+    master, _, client = make_cloud(tmp_path, chunk_size=800 * 16)
+    pts = np.random.default_rng(0).normal(size=(3000, 4)).astype(np.float32)
+    client.upload("pts", encode_points(pts))
+    eng = SphereEngine(master, client)
+    assert eng.tracer is NULL_TRACER
+    kmeans_sphere(eng, "pts", dim=4, k=3, iters=2, backend="array")
+    assert not _listening()
+    assert trace_mod._COMPILE_EVENTS._live() == []
