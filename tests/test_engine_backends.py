@@ -10,11 +10,14 @@ diffing SphereReports across backends."""
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from conftest import make_cloud
-from repro.core import SphereEngine, SphereJob, SphereStage
-from repro.core.records import RecordBatch
+from repro.core import SphereEngine, SphereJob, SphereStage, Tracer
+from repro.core.executor import (SLAB_MIN_BYTES, ArrayExecutor, _SlotRef,
+                                 _as_batch, _slab)
+from repro.core.records import RecordBatch, StackedBatch
 from repro.core.shuffle import (reduce_partitioner, sample_boundaries,
                                 terasort_stages)
 
@@ -451,3 +454,90 @@ def test_pad_unstable_udf_is_rejected(tmp_path):
                     record_size=REC, backend="array")
     with pytest.raises(ValueError, match="pad-stable"):
         SphereEngine(master, client).run(job)
+
+
+# ------------------------- output copy-out as a slab ------------------------
+
+def _junk_stack(width):
+    """Three slots whose padded rows fill just over SLAB_MIN_BYTES, every
+    byte non-zero, padded tails included."""
+    block = -(-SLAB_MIN_BYTES // width) + 17
+    data = np.random.default_rng(width).integers(
+        1, 256, (3, block, width), dtype=np.uint8)
+    return StackedBatch(jnp.asarray(data),
+                        np.array([block - 5, 7, block // 2], np.int32))
+
+
+@pytest.mark.parametrize("kind", ["slot", "concat"])
+@pytest.mark.parametrize("width", [100, 80, 13])
+def test_slab_copy_out_matches_rows(width, kind):
+    """A stack slot above the threshold crosses as a uint32 slab, and its
+    output bytes are the valid rows', byte for byte: the junk tails and
+    the slab's own zero padding never leak (width 13 makes a padded slot
+    that is not a whole number of 512-byte slab rows).  A worker that
+    owns several slots arrives as a concatenated batch and crosses as
+    its rows, with the same bytes."""
+    st = _junk_stack(width)
+    if kind == "slot":
+        parts = {f"w{i}": _SlotRef(st, i) for i in range(st.n_slots)}
+    else:  # a worker that owns several slots
+        parts = {"w0": RecordBatch.concat([st.slot(0), st.slot(1)])}
+    want = [np.asarray(_as_batch(p).data)[:p.num_records].tobytes()
+            for p in parts.values()]
+    tracer = Tracer()
+    got = ArrayExecutor(None, list(parts), tracer=tracer).outputs(parts)
+    assert got == want
+    layouts = [e.attrs["layout"] for e in tracer.snapshot()
+               if e.name == "d2h"]
+    assert layouts == ["slab" if kind == "slot" else "rows"] * len(parts)
+
+
+def test_slab_compiles_once_per_stack_shape():
+    """The pack is keyed on the stack's padded shape, never on a record
+    count: slots of two stacks with different valid counts share one
+    executable, and concatenated batches (whose row count the data sets)
+    add none, while their bytes stay the valid rows'."""
+    width = 52  # a width no other test packs, so the count is this test's
+    st = _junk_stack(width)
+    other = StackedBatch(st.data, np.array([3, st.block_rows, 11], np.int32))
+    before = _slab._cache_size()
+    for stack in (st, other):
+        slots = {f"w{i}": _SlotRef(stack, i) for i in range(stack.n_slots)}
+        concat = {"w0": RecordBatch.concat([stack.slot(0), stack.slot(2)])}
+        for parts in (slots, concat):
+            want = [np.asarray(_as_batch(p).data)[:p.num_records].tobytes()
+                    for p in parts.values()]
+            assert ArrayExecutor(None, list(parts)).outputs(parts) == want
+    assert _slab._cache_size() == before + 1
+
+
+def test_small_slot_crosses_as_rows():
+    """A slot below the threshold keeps the row copy, so the pack never
+    compiles for it."""
+    data = np.random.default_rng(1).integers(1, 256, (2, 64, 100), np.uint8)
+    st = StackedBatch(jnp.asarray(data), np.array([40, 64], np.int32))
+    assert st.block_rows * 100 < SLAB_MIN_BYTES
+    parts = {"w0": _SlotRef(st, 0), "w1": _SlotRef(st, 1)}
+    tracer = Tracer()
+    got = ArrayExecutor(None, list(parts), tracer=tracer).outputs(parts)
+    assert got == [data[0, :40].tobytes(), data[1].tobytes()]
+    assert [e.attrs["layout"] for e in tracer.snapshot()
+            if e.name == "d2h"] == ["rows", "rows"]
+
+
+@pytest.mark.parametrize("width", [100, 80, 13])
+def test_slab_host_array_is_c_contiguous(width):
+    st = _junk_stack(width)
+    host = np.asarray(_slab(st.data, 1))
+    assert host.dtype == np.uint32 and host.shape[1] == 128
+    assert host.flags.c_contiguous
+    flat = host.view(np.uint8).reshape(-1)
+    n = st.block_rows * width
+    assert flat[:n].tobytes() == np.asarray(st.data[1]).tobytes()
+    assert flat.size - n < 512 and not flat[n:].any()
+
+
+def test_terasort_slot_packs_without_padding():
+    slot = jax.ShapeDtypeStruct((6, 1835008, 100), jnp.uint8)
+    out = jax.eval_shape(_slab, slot, 0)
+    assert (out.shape, out.dtype) == ((358400, 128), jnp.uint32)

@@ -316,9 +316,15 @@ def _children(tracer, parent, name):
             if e.name == name and e.parent_id == parent.span_id]
 
 
-@pytest.mark.parametrize("backend", ["bytes", "array"])
-def test_one_materialise_per_job_run(tmp_path, backend):
-    out, _, _, tracer = _run_terasort(tmp_path, backend, Tracer())
+@pytest.mark.parametrize("backend,layout",
+                         [("bytes", None), ("array", "rows"),
+                          ("array", "slab")],
+                         ids=["bytes", "array", "array-slab"])
+def test_one_materialise_per_job_run(tmp_path, backend, layout):
+    # 1,500 records leave each output slot a few hundred KB padded, below
+    # executor.SLAB_MIN_BYTES; 48,000 leave each above it
+    n = 48000 if layout == "slab" else 1500
+    out, _, _, tracer = _run_terasort(tmp_path, backend, Tracer(), n=n)
     assert tracer.count("materialise") == 1
     if backend == "bytes":
         assert tracer.count("d2h") == tracer.count("output-wait") == 0
@@ -334,6 +340,22 @@ def test_one_materialise_per_job_run(tmp_path, backend):
         (xfer,) = _children(tracer, e, "d2h-transfer")
         (pack,) = _children(tracer, e, "d2h-tobytes")
         assert e.t0 <= xfer.t0 <= xfer.t1 <= pack.t0 <= pack.t1 <= e.t1
+        assert e.attrs["layout"] == layout
+
+
+def test_kmeans_session_outputs_cross_as_rows(tmp_path):
+    """A k-means fold's output is one row of partial sums, far below the
+    slab threshold: its copy-out keeps the row layout."""
+    master, _, client = make_cloud(tmp_path, chunk_size=800 * 16)
+    pts = np.random.default_rng(0).normal(size=(3000, 4)).astype(np.float32)
+    client.upload("pts", encode_points(pts))
+    eng = SphereEngine(master, client, tracer=Tracer())
+    sess = eng.session("pts", record_size=16, backend="array")
+    kmeans_sphere(eng, "pts", dim=4, k=3, iters=2, backend="array",
+                  session=sess)
+    d2h = [e for e in eng.tracer.snapshot() if e.name == "d2h"]
+    assert len(d2h) == 2
+    assert all(e.attrs["layout"] == "rows" for e in d2h)
 
 
 @pytest.mark.parametrize("prefetch", [True, False])
