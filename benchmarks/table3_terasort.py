@@ -377,9 +377,8 @@ def run_tracing(n_records: int = 50_000, *, best_of: int = 7,
 
 _DEVICE_BENCH = """
 import jax, jax.numpy as jnp, numpy as np
-from repro.core.spmd import distributed_sort, barrier_sort
-from repro.launch.mesh import make_flat_mesh
-mesh = make_flat_mesh()
+from repro.core.spmd import data_mesh, distributed_sort, barrier_sort
+mesh = data_mesh()
 N = {n}
 keys = jax.random.randint(jax.random.PRNGKey(0), (N,), 0, 1 << 30,
                           dtype=jnp.uint32)

@@ -37,6 +37,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.job import SphereJob, SphereStage
 from repro.core.planner import SphereReport, StagePlan
@@ -94,10 +95,11 @@ class _ExecutorBase:
                 self.client.run_repair()
         return None
 
-    def _stage0_input(self, job: SphereJob, key: str, rep: SphereReport):
+    def _stage0_input(self, job: SphereJob, key: str, rep: SphereReport,
+                      device=None):
         """Decoded stage-0 input for one chunk task, through the session
-        chunk cache when enabled.  Returns None when every replica is
-        gone."""
+        chunk cache when enabled (put on ``device``, where given).
+        Returns None when every replica is gone."""
         if self._chunk_cache is not None and key in self._chunk_cache:
             return self._chunk_cache[key]
         with self.tracer.span("fetch-chunk", track="fetch",
@@ -107,14 +109,14 @@ class _ExecutorBase:
             if blob is None:
                 sp.set_attrs(lost=True)
                 return None
-            decoded = self._decode_chunk(job, blob, "fetch")
+            decoded = self._decode_chunk(job, blob, "fetch", device)
         if self._chunk_cache is not None:
             self._chunk_cache[key] = decoded
         return decoded
 
     # ------------------------------------------------- stage-0 prefetch
-    def _stage0_batches(self, job: SphereJob, tasks, rep: SphereReport
-                        ) -> Iterator[tuple]:
+    def _stage0_batches(self, job: SphereJob, tasks, rep: SphereReport,
+                        devices=None) -> Iterator[tuple]:
         """Yield ``(task, decoded_input)`` for the stage-0 task list with
         a ``prefetch_depth``-deep fetch+decode pipeline: ONE producer
         thread walks the chunks strictly in task order — so Sector
@@ -127,17 +129,21 @@ class _ExecutorBase:
         attempt one, so ``rep.retried`` and repair behaviour are
         bit-identical with prefetching off (and across depths).
         ``decoded_input`` is None when every replica of a chunk is gone
-        (the caller skips the task).  The consumer's wait on the queue is
+        (the caller skips the task).  ``devices``, aligned with
+        ``tasks``, names the device each chunk is put on (the default
+        device where None).  The consumer's wait on the queue is
         the ``prefetch-wait`` span: time the data plane stood waiting on
         Sector, where ``fetch-chunk`` is fetch work that overlaps it."""
+        if devices is None:
+            devices = [None] * len(tasks)
         if not self.prefetch or len(tasks) <= 1:
-            for t in tasks:
-                yield t, self._stage0_input(job, t.key, rep)
+            for t, dev in zip(tasks, devices):
+                yield t, self._stage0_input(job, t.key, rep, dev)
             return
         q: "queue.Queue[tuple]" = queue.Queue(maxsize=self.prefetch_depth)
 
         def produce():
-            for t in tasks:
+            for t, dev in zip(tasks, devices):
                 if self._chunk_cache is not None \
                         and t.key in self._chunk_cache:
                     # cache hits are resolved by the consumer (the cache
@@ -150,7 +156,8 @@ class _ExecutorBase:
                         with self.tracer.span("sector-read",
                                               track="prefetch"):
                             blob = self.client.read_chunk(t.key)
-                        payload = self._decode_chunk(job, blob, "prefetch")
+                        payload = self._decode_chunk(job, blob, "prefetch",
+                                                     dev)
                     q.put(("ok", payload))
                 except (IOError, ServerDown):
                     q.put(("retry", None))
@@ -161,7 +168,7 @@ class _ExecutorBase:
         th = threading.Thread(target=produce, daemon=True,
                               name="sphere-prefetch")
         th.start()
-        for t in tasks:
+        for t, dev in zip(tasks, devices):
             with self.tracer.span("prefetch-wait", track="fetch"):
                 kind, payload = q.get()
             if kind == "ok":
@@ -169,7 +176,7 @@ class _ExecutorBase:
                     self._chunk_cache[t.key] = payload
                 yield t, payload
             elif kind in ("cache", "retry"):
-                yield t, self._stage0_input(job, t.key, rep)
+                yield t, self._stage0_input(job, t.key, rep, dev)
             else:
                 raise payload
         th.join()
@@ -184,8 +191,8 @@ class BytesExecutor(_ExecutorBase):
     def part_sizes(self, parts) -> Dict[str, int]:
         return {w: sum(len(r) for r in parts[w]) for w in self.workers}
 
-    def _decode_chunk(self, job: SphereJob, blob: bytes, track: str
-                      ) -> List[bytes]:
+    def _decode_chunk(self, job: SphereJob, blob: bytes, track: str,
+                      device=None) -> List[bytes]:
         return job.split_records(blob)
 
     def run_stage(self, job: SphereJob, stage: SphereStage, plan: StagePlan,
@@ -454,6 +461,31 @@ def _slab(data: jax.Array, idx) -> jax.Array:
     return jnp.pad(flat, (0, -flat.size % 128)).reshape(-1, 128)
 
 
+def _slot_shard(data: jax.Array, idx: int):
+    """``(shard, index)``: the one-device piece of the stack ``data``
+    that holds slot ``idx`` whole, and the slot's index in it — the
+    stack itself when it sits on one device; ``(None, None)`` where no
+    addressable shard holds the slot whole."""
+    for shard in data.addressable_shards:
+        lo, hi, _ = shard.index[0].indices(data.shape[0])
+        if lo <= idx < hi and shard.data.shape[1:] == data.shape[1:]:
+            return shard.data, idx - lo
+    return None, None
+
+
+@functools.partial(jax.jit, static_argnames=("target", "n_empty"))
+def _stack_rows(pieces, *, target: int, n_empty: int) -> jax.Array:
+    """The 2-D ``pieces`` (on one device) as a [len + n_empty, target,
+    width] stack: each cut or zero-padded to ``target`` rows, then
+    ``n_empty`` zero slots."""
+    width = pieces[0].shape[1]
+    blocks = [p[:target] if p.shape[0] >= target
+              else jnp.pad(p, ((0, target - p.shape[0]), (0, 0)))
+              for p in pieces]
+    blocks += [jnp.zeros((target, width), jnp.uint8)] * n_empty
+    return jnp.stack(blocks)
+
+
 @dataclass
 class _StackedOut:
     """A fused run_stage result: the whole stage output as ONE
@@ -488,9 +520,13 @@ class ArrayExecutor(_ExecutorBase):
     per-task/per-worker calls (see :class:`_StackedOut`,
     :func:`scatter_round_dispatch` and :class:`FusedRoundResult`).
     Mask-aware, shape-polymorphic and host-loop shapes keep the
-    per-task path.  Supplying ``mesh`` lowers the fused round through
-    ``shard_map`` over the mesh's ``data`` axis with the bucket exchange
-    as ``lax.all_to_all`` (``core.spmd.fused_scatter_round``)."""
+    per-task path.  Supplying ``mesh`` (a 1-D ``data`` mesh,
+    ``core.spmd.data_mesh``) spreads each stacked stage over its
+    devices, a device holding a contiguous run of slots: stage-0 chunks
+    are put straight on their slot's device, the round runs through
+    ``shard_map`` with the bucket exchange as ``lax.all_to_all``
+    (``core.spmd.fused_scatter_round``), and each later stage applies
+    per device to the workers it holds."""
 
     def __init__(self, client, workers: Sequence[str], max_retries: int = 3,
                  pad_block: int = 4096, cache_chunks: bool = False,
@@ -505,6 +541,9 @@ class ArrayExecutor(_ExecutorBase):
         # the mesh only carries rounds whose slot/worker counts divide
         # its data axis; others use the single-device lowering, and
         # their shuffle-round span says so (path "fused", not "mesh")
+        if mesh is not None and tuple(mesh.axis_names) != ("data",):
+            raise ValueError(f"the data plane's mesh has the one axis "
+                             f"'data', not {mesh.axis_names}")
         self.mesh = mesh
         # benchmark honesty knob: block on every shuffled piece before
         # stopping the partition_seconds clock, so deferred-sync timing
@@ -520,13 +559,14 @@ class ArrayExecutor(_ExecutorBase):
         return {w: (parts[w].nbytes if parts[w] is not None else 0)
                 for w in self.workers}
 
-    def _decode_chunk(self, job: SphereJob, blob: bytes, track: str
-                      ) -> RecordBatch:
+    def _decode_chunk(self, job: SphereJob, blob: bytes, track: str,
+                      device=None) -> RecordBatch:
         # the split is a zero-copy view: what takes the time is the put,
         # whose host side this span holds; its transfer runs on after it
+        dev = device if device is not None else jax.devices()[0]
         with self.tracer.span("h2d-put", track=track,
-                              attrs={"bytes": len(blob)}):
-            return job.split_batch(blob)
+                              attrs={"bytes": len(blob), "device": dev.id}):
+            return job.split_batch(blob, device)
 
     # --------------------------------------------------------- UDF apply
     def _traced_for(self, stage: SphereStage, udf, *,
@@ -664,11 +704,46 @@ class ArrayExecutor(_ExecutorBase):
     def _mesh_slots(self, n: int) -> int:
         """Slot count padded up to a multiple of the mesh data axis (the
         shard_map sharding requirement); extra slots ride through with
-        zero valid rows.  1 when no mesh is bound."""
+        zero valid rows.  ``n`` when no mesh is bound."""
         if self.mesh is None:
             return n
-        d = self.mesh.shape.get("data", 1)
+        d = self.mesh.shape["data"]
         return -(-n // d) * d
+
+    def _slot_devices(self, owners: Sequence[int]) -> list:
+        """The device of each item's slot, for items whose slots are
+        ordered by ``owners`` (stable, worker-major): a mesh device
+        holds a contiguous run of ``_mesh_slots(n) / d`` slots."""
+        devs = list(self.mesh.devices.flat)
+        per = self._mesh_slots(len(owners)) // len(devs)
+        out = [None] * len(owners)
+        for slot, i in enumerate(sorted(range(len(owners)),
+                                        key=owners.__getitem__)):
+            out[i] = devs[slot // per]
+        return out
+
+    def _shard_stack(self, pieces: Sequence[jax.Array], target: int,
+                     width: int, rep: SphereReport) -> jax.Array:
+        """The [S, target, width] stack of ``pieces`` sharded over the
+        mesh, built device by device: each device stacks only its own
+        slots' pieces (moved there first, should one sit elsewhere), and
+        the slots past ``pieces`` (up to ``_mesh_slots``) are empty."""
+        devs = list(self.mesh.devices.flat)
+        s = self._mesh_slots(len(pieces))
+        per = s // len(devs)
+        shards = []
+        for k, dev in enumerate(devs):
+            own = tuple(jax.device_put(p, dev)
+                        for p in pieces[k * per:(k + 1) * per])
+            if own:
+                shards.append(_stack_rows(own, target=target,
+                                          n_empty=per - len(own)))
+                rep.device_dispatches += 1
+            else:
+                shards.append(jax.device_put(
+                    np.zeros((per, target, width), np.uint8), dev))
+        return jax.make_array_from_single_device_arrays(
+            (s, target, width), NamedSharding(self.mesh, P("data")), shards)
 
     def _aligned_stacked(self, parts) -> Optional[StackedBatch]:
         """The previous fused round's StackedBatch, when every worker's
@@ -722,7 +797,11 @@ class ArrayExecutor(_ExecutorBase):
                     np.arange(stacked.n_slots, dtype=np.int64))
         items: List[Tuple[int, RecordBatch]] = []
         if first_stage:
-            for t, batch in self._stage0_batches(job, plan.tasks, rep):
+            # on a mesh each chunk is put straight on its slot's device
+            devices = None if self.mesh is None else self._slot_devices(
+                [windex[t.executor] for t in plan.tasks])
+            for t, batch in self._stage0_batches(job, plan.tasks, rep,
+                                                 devices):
                 if batch is not None and batch.num_records:
                     items.append((windex[t.executor], batch))
         else:
@@ -741,19 +820,22 @@ class ArrayExecutor(_ExecutorBase):
         slot_workers = np.fromiter((i for i, _ in items), np.int64,
                                    count=len(items))
         pieces = [b.data for _, b in items]
-        pad_slots = self._mesh_slots(len(items)) - len(items)
-        if pad_slots:
-            zero = jnp.zeros((target, items[0][1].record_size), jnp.uint8)
-            pieces.extend([zero] * pad_slots)
+        if self.mesh is None:
+            out = traced.stack_pieces(pieces,
+                                      jnp.asarray(n_valid, jnp.int32), target)
+        else:
+            pad_slots = self._mesh_slots(len(items)) - len(items)
             n_valid = np.concatenate([n_valid,
                                       np.zeros(pad_slots, np.int32)])
             slot_workers = np.concatenate(
                 [slot_workers, np.zeros(pad_slots, np.int64)])
-        out = traced.stack_pieces(pieces, jnp.asarray(n_valid, jnp.int32),
-                                  target)
+            stack = self._shard_stack(pieces, target,
+                                      items[0][1].record_size, rep)
+            out = traced.stacked(stack, jnp.asarray(n_valid, jnp.int32),
+                                 target)
         rep.device_dispatches += 1
         self._note_traces(stage, traced, rep)
-        self._check_stacked(stage, out, len(pieces), target)
+        self._check_stacked(stage, out, len(n_valid), target)
         return _StackedOut(StackedBatch(out, n_valid), slot_workers)
 
     # ----------------------------------------------------------- shuffle
@@ -765,10 +847,11 @@ class ArrayExecutor(_ExecutorBase):
         partitioner) — the caller then uses the single-device fused
         lowering."""
         from repro.core.shuffle import ReducePartitioner
-        from repro.core.spmd import fused_scatter_round
+        from repro.core.spmd import (fused_scatter_round, mesh_round_capacity,
+                                     mesh_round_regrow)
         stacked = out.stacked
         W, S = len(self.workers), stacked.n_slots
-        d = self.mesh.shape.get("data", 1)
+        d = self.mesh.shape["data"]
         if W % d or S % d or n <= 1 \
                 or isinstance(stage.partitioner, ReducePartitioner) \
                 or getattr(stage.partitioner, "scatter_spec", None) is None:
@@ -778,20 +861,38 @@ class ArrayExecutor(_ExecutorBase):
         if spec is None:
             return None
         key_spec, bounds = spec
+        rows = stacked.block_rows
+        cap, wcap = mesh_round_capacity(stacked.n_valid, rows, n_buckets=n,
+                                        n_workers=W, d=d)
+        n_valid = jnp.asarray(stacked.n_valid, jnp.int32)
         rep.shuffle_rounds += 1
+        rep.mesh_rounds += 1
         with self.tracer.span("shuffle-round", track="shuffle",
                               attrs={"backend": "array", "path": "mesh",
                                      "buckets": n}) as sp:
-            parts_dev, counts_dev, hist_dev = fused_scatter_round(
-                stacked.data, jnp.asarray(stacked.n_valid, jnp.int32),
-                bounds, key_spec=key_spec, n_buckets=n, n_workers=W,
-                mesh=self.mesh)
-            rep.device_dispatches += 1
-            counts, hist_sb = jax.device_get((counts_dev, hist_dev))
-            rep.host_syncs += 1
-            if self.tracer.enabled:
-                self.tracer.instant("host-sync", track="host-sync",
-                                    attrs={"where": "mesh-harvest"})
+            regrow = False
+            while True:
+                parts_dev, counts_dev, hist_dev, over_dev = \
+                    fused_scatter_round(stacked.data, n_valid, bounds,
+                                        key_spec=key_spec, n_buckets=n,
+                                        n_workers=W, mesh=self.mesh,
+                                        cap=cap, wcap=wcap)
+                rep.device_dispatches += 1
+                counts, hist_sb, over = jax.device_get(
+                    (counts_dev, hist_dev, over_dev))
+                rep.host_syncs += 1
+                if self.tracer.enabled:
+                    self.tracer.instant("host-sync", track="host-sync",
+                                        attrs={"where": "mesh-harvest"})
+                if not over.any():
+                    break
+                # a section or a partition outgrew its capacity and left
+                # records out: redo the round from the same input, larger
+                cap, wcap = mesh_round_regrow(hist_sb, rows, cap, wcap,
+                                              n_workers=W, d=d)
+                rep.exchange_regrows += 1
+                regrow = True
+            sp.set_attrs(capacity=cap, regrow=regrow)
             origin_counts = np.zeros((n, W), np.int64)
             for s in range(S):
                 origin_counts[:, int(out.slot_workers[s])] += hist_sb[s]
@@ -1008,12 +1109,13 @@ class ArrayExecutor(_ExecutorBase):
         and ``d2h`` the copy to the host: ``d2h-transfer`` the device
         array to a host one, ``d2h-tobytes`` its valid bytes to
         ``bytes``.  A stack slot (``_SlotRef``) of at least
-        ``SLAB_MIN_BYTES`` padded bytes on one device crosses as a
-        :func:`_slab` (attr ``layout=slab``, the pack's dispatch inside
+        ``SLAB_MIN_BYTES`` padded bytes crosses as a :func:`_slab` of the
+        one-device shard that holds it, the whole stack when it sits on
+        one device (attr ``layout=slab``, the pack's dispatch inside
         ``d2h-transfer``).  Every other partition crosses as its rows
-        (``layout=rows``): small or sharded slots, and 2-D batches, whose
-        row count a concat sets from the data, so a pack of one would
-        compile once per record count."""
+        (``layout=rows``): small slots, and 2-D batches, whose row count
+        a concat sets from the data, so a pack of one would compile once
+        per record count."""
         out = []
         with self.tracer.span("materialise", track="output"):
             for w in self.workers:
@@ -1022,9 +1124,9 @@ class ArrayExecutor(_ExecutorBase):
                     continue
                 slab = False
                 if isinstance(part, _SlotRef):
-                    data, idx = part.stacked.data, part.idx
-                    slab = (data.shape[-2] * data.shape[-1] >= SLAB_MIN_BYTES
-                            and len(data.sharding.device_set) == 1)
+                    data, idx = _slot_shard(part.stacked.data, part.idx)
+                    slab = (data is not None and data.shape[-2]
+                            * data.shape[-1] >= SLAB_MIN_BYTES)
                 if not slab:
                     data = _as_batch(part).data
                 with self.tracer.span("output-wait", track="output"):

@@ -123,5 +123,5 @@ class SphereJob:
         rs = self.record_size
         return [blob[i:i + rs] for i in range(0, len(blob) - rs + 1, rs)]
 
-    def split_batch(self, blob: bytes) -> RecordBatch:
-        return RecordBatch.from_bytes(blob, self.record_size)
+    def split_batch(self, blob: bytes, device=None) -> RecordBatch:
+        return RecordBatch.from_bytes(blob, self.record_size, device)

@@ -68,7 +68,7 @@ _MIRRORED_COUNTERS = frozenset({
     "speculation_wins", "retried", "partition_seconds",
     "partitioned_records", "planned_tasks", "reused_tasks",
     "shuffle_rounds", "host_syncs", "device_dispatches",
-    "link_wait_seconds"})
+    "link_wait_seconds", "mesh_rounds", "exchange_regrows"})
 
 
 @dataclass
@@ -118,6 +118,12 @@ class SphereReport:
     # the planner runs contention-blind or every move rode a private
     # path).  The gap between a contention-blind estimate and reality.
     link_wait_seconds: float = 0.0
+    # mesh data plane: shuffle rounds that ran as one shard_map program
+    # with an all_to_all exchange, and how many of those were redone at
+    # a larger exchange capacity after a send section or a worker
+    # partition overflowed (each redo is one more dispatch and sync)
+    mesh_rounds: int = 0
+    exchange_regrows: int = 0
 
     # ------------------------------------------------------ metrics mirror
     def bind_metrics(self, registry, **labels) -> "SphereReport":

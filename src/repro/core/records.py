@@ -146,14 +146,18 @@ class RecordBatch:
 
     # ------------------------------------------------------------ codecs
     @staticmethod
-    def from_bytes(blob: bytes, record_size: int) -> "RecordBatch":
+    def from_bytes(blob: bytes, record_size: int, device=None
+                   ) -> "RecordBatch":
+        """The records of ``blob`` on ``device`` (the default device
+        where None)."""
         if record_size <= 0:
             raise ValueError("array backend needs a fixed record_size > 0")
         if len(blob) % record_size:
             raise ValueError(f"blob of {len(blob)} bytes is not a multiple "
                              f"of record_size {record_size}")
         arr = np.frombuffer(blob, np.uint8).reshape(-1, record_size)
-        return RecordBatch(jnp.asarray(arr))
+        return RecordBatch(jnp.asarray(arr) if device is None
+                           else jax.device_put(arr, device))
 
     @staticmethod
     def from_records(records: Sequence[bytes]) -> "RecordBatch":

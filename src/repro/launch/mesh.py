@@ -28,7 +28,3 @@ def make_debug_mesh(*, multi_pod: bool = False):
         return make_auto_mesh((1, n, 1), ("pod", "data", "model"))
     return make_auto_mesh((n, 1), ("data", "model"))
 
-
-def make_flat_mesh(axis: str = "data"):
-    """1-D mesh over all devices (Sphere SPMD jobs, sort benchmarks)."""
-    return make_auto_mesh((jax.device_count(),), (axis,))

@@ -222,11 +222,12 @@ def test_mesh_fused_round_matches_host_harvest():
     stacked = _pack(slots, rec)
     key_spec, bounds = part.scatter_spec(RecordBatch.empty(rec), n)
     mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
-    parts_dev, counts_dev, hist_sb = fused_scatter_round(
+    parts_dev, counts_dev, hist_sb, over = fused_scatter_round(
         stacked.data, jnp.asarray(stacked.n_valid, jnp.int32),
         bounds, key_spec=key_spec, n_buckets=n, n_workers=W, mesh=mesh)
     want_parts, want_counts, _ = _reference(slots, slot_workers, WORKERS,
                                             part, n)
+    assert not np.asarray(over).any()
     counts = np.asarray(counts_dev)
     assert counts.tolist() == want_counts
     got = np.asarray(parts_dev)

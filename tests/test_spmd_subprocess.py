@@ -33,8 +33,8 @@ def test_distributed_sort_correct():
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.core.spmd import distributed_sort
-        from repro.launch.mesh import make_flat_mesh
-        mesh = make_flat_mesh()
+        from repro.core.spmd import data_mesh
+        mesh = data_mesh()
         keys = jax.random.randint(jax.random.PRNGKey(0), (1<<13,), 0, 1<<30,
                                   dtype=jnp.uint32)
         outp, valid = distributed_sort(keys, mesh)
@@ -58,8 +58,8 @@ def test_fused_scatter_round_multidevice_matches_host():
         from repro.core.records import RecordBatch, StackedBatch
         from repro.core.shuffle import hash_partitioner
         from repro.core.spmd import fused_scatter_round
-        from repro.launch.mesh import make_flat_mesh
-        mesh = make_flat_mesh()                 # 8 devices on axis 'data'
+        from repro.core.spmd import data_mesh
+        mesh = data_mesh()                      # 8 devices on axis 'data'
         rec, n, W, S = 12, 11, 16, 24           # S slots, W workers, n buckets
         rng = np.random.default_rng(0)
         loads = rng.integers(0, 30, size=S)
@@ -70,7 +70,7 @@ def test_fused_scatter_round_multidevice_matches_host():
         stacked = StackedBatch.pack(batches, pad_block=8)
         part = hash_partitioner(key_bytes=8)
         key_spec, bounds = part.scatter_spec(RecordBatch.empty(rec), n)
-        parts, counts, hist = fused_scatter_round(
+        parts, counts, hist, over = fused_scatter_round(
             stacked.data, jnp.asarray(stacked.n_valid, jnp.int32), bounds,
             key_spec=key_spec, n_buckets=n, n_workers=W, mesh=mesh)
         # host reference: bucket append order = slot-major, input order
@@ -83,6 +83,7 @@ def test_fused_scatter_round_multidevice_matches_host():
         for b in range(n):
             want[b % W] += b''.join(buckets[b])
             wc[b % W] += len(buckets[b])
+        assert not np.asarray(over).any()
         counts = np.asarray(counts)
         assert counts.tolist() == wc, (counts.tolist(), wc)
         got = np.asarray(parts)

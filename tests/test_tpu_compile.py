@@ -28,7 +28,8 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
 from repro.core.executor import _TracedUDF, _slab
 from repro.core.kmeans import make_kmeans_stages
 from repro.core.shuffle import _scatter_stacked
-from repro.core.spmd import fused_scatter_round
+from repro.core.spmd import (fused_scatter_round, mesh_round,
+                             mesh_round_capacity)
 from repro.kernels.bucket_partition.kernel import (bucket_dest_call,
                                                    bucket_partition_call,
                                                    bucket_scatter_call)
@@ -147,6 +148,44 @@ def test_mesh_round_compiles(topo):
                                  sharding=sharded),
         jax.ShapeDtypeStruct((8,), jnp.int32, sharding=sharded))
     assert "all-to-all" in compiled.as_text()
+
+
+def test_mesh_round_fits_a_chip_at_the_mesh_cells_share(topo):
+    """The share-sized mesh round at ``terasort-gray-mesh4``'s shape: 60
+    stage-0 slots of 786432 rows (15 a chip, 64 MiB chunks of 671088
+    records), 8 workers and 8 buckets on the 2x2 host, the exchange
+    sized by ``mesh_round_capacity``.  What a chip holds for it fits the
+    v5e's 16 GB (sized from the whole block, its send, receive and
+    regroup buffers alone came to about 19 GB); its module is
+    ``jit_mesh_round`` with the collective's op in it, as the device
+    trace readers of the cell name them."""
+    import re
+    from bench.registry import load_module
+    mesh = Mesh(np.array(topo.devices[:4]), ("data",))
+    sharded = NamedSharding(mesh, P("data"))
+    slots, rows = 60, 786432
+    cap, wcap = mesh_round_capacity(np.full(slots, 671088), rows,
+                                    n_buckets=8, n_workers=8, d=4)
+    compiled = mesh_round.lower(
+        jax.ShapeDtypeStruct((slots, rows, RECORD), jnp.uint8,
+                             sharding=sharded),
+        jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=sharded),
+        jax.ShapeDtypeStruct((7, 3), jnp.uint32,
+                             sharding=NamedSharding(mesh, P())),
+        key_spec=("range", 10, 3, None), n_buckets=8, n_workers=8,
+        mesh=mesh, axis="data", cap=cap, wcap=wcap,
+        interpret=False).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes + mem.generated_code_size_in_bytes
+             - mem.alias_size_in_bytes)
+    assert total < 16e9, total
+    module, ops_text = _module_and_ops(compiled)
+    assert module == "jit_mesh_round"
+    assert re.match(load_module("metrics", "mesh_round_roofline").MODULE,
+                    f"{module}/x")
+    rx = re.compile(load_module("metrics", "collective_s").OP)
+    assert len([op for op in ops_text if rx.search(op)]) == 2
 
 
 def _module_and_ops(compiled):
