@@ -25,6 +25,7 @@ workers and simulated time agrees across backends for the same job.
 """
 from __future__ import annotations
 
+import collections
 import functools
 import math
 import queue
@@ -1104,50 +1105,80 @@ class ArrayExecutor(_ExecutorBase):
 
     def outputs(self, parts) -> List[bytes]:
         """The ONLY host materialisation of record data after stage 0.
-        Per partition, ``output-wait`` holds the wait for the device
-        work behind it (the wait ``np.asarray`` would make, made first)
-        and ``d2h`` the copy to the host: ``d2h-transfer`` the device
-        array to a host one, ``d2h-tobytes`` its valid bytes to
-        ``bytes``.  A stack slot (``_SlotRef``) of at least
-        ``SLAB_MIN_BYTES`` padded bytes crosses as a :func:`_slab` of the
-        one-device shard that holds it, the whole stack when it sits on
-        one device (attr ``layout=slab``, the pack's dispatch inside
-        ``d2h-transfer``).  Every other partition crosses as its rows
+        A stack slot (``_SlotRef``) of at least ``SLAB_MIN_BYTES`` padded
+        bytes crosses as a :func:`_slab` of the one-device shard that
+        holds it, the whole stack when it sits on one device (attr
+        ``layout=slab``).  Every other partition crosses as its rows
         (``layout=rows``): small slots, and 2-D batches, whose row count
         a concat sets from the data, so a pack of one would compile once
-        per record count."""
-        out = []
-        with self.tracer.span("materialise", track="output"):
+        per record count.
+
+        Copies are issued ahead of the host's: ``d2h-issue`` dispatches a
+        partition's pack and starts its ``copy_to_host_async``, in worker
+        order, while each of its devices holds fewer than two issued
+        copies (the one being turned into ``bytes`` and the next), so a
+        transfer runs under the previous partition's ``tobytes`` and
+        different devices' transfers run together.  Then, per partition
+        in worker order, ``output-wait`` holds the wait for the device
+        work behind it and ``d2h`` the copy to the host: ``d2h-transfer``
+        the wait for what is left of the issued copy, ``d2h-tobytes`` its
+        valid bytes to ``bytes``.  ``d2h`` attr ``ahead``: the copy was
+        issued before the previous partition's ``d2h-tobytes`` began;
+        ``materialise`` attr ``ahead`` counts them."""
+        with self.tracer.span("materialise", track="output") as msp:
+            todo = []
             for w in self.workers:
                 part = parts[w]
                 if part is None or not part.num_records:
                     continue
-                slab = False
+                data = None
                 if isinstance(part, _SlotRef):
                     data, idx = _slot_shard(part.stacked.data, part.idx)
-                    slab = (data is not None and data.shape[-2]
-                            * data.shape[-1] >= SLAB_MIN_BYTES)
-                if not slab:
-                    data = _as_batch(part).data
+                if data is not None and (data.shape[-2] * data.shape[-1]
+                                         >= SLAB_MIN_BYTES):
+                    todo.append((data, idx, part.nbytes))
+                else:
+                    todo.append((_as_batch(part).data, None, part.num_records))
+            out, issued, live = [], collections.deque(), collections.Counter()
+            nxt = n_ahead = issued_before = 0
+            for k, (data, idx, end) in enumerate(todo):
+                while nxt < len(todo) and all(
+                        live[d] < 2 for d in todo[nxt][0].devices()):
+                    issued.append(self._issue_copy(*todo[nxt][:2]))
+                    live.update(todo[nxt][0].devices())
+                    nxt += 1
+                ahead = k < issued_before
                 with self.tracer.span("output-wait", track="output"):
                     jax.block_until_ready(data)
                 with self.tracer.span("d2h", track="output") as sp:
                     with self.tracer.span("d2h-transfer", track="output"):
-                        if slab:
-                            # the slab is dropped once its host copy is
-                            # made, so at most one is live at a time
-                            host = np.asarray(_slab(data, idx))
+                        arr = issued.popleft()
+                        host = np.asarray(arr)
+                        if idx is not None:
                             host = host.view(np.uint8).reshape(-1)
-                            end = part.nbytes
-                        else:
-                            host = np.asarray(data)
-                            end = part.num_records
+                    issued_before = nxt
                     with self.tracer.span("d2h-tobytes", track="output"):
                         # valid records only: padding never leaks out
                         out.append(host[:end].tobytes())
-                    sp.set_attrs(bytes=len(out[-1]),
-                                 layout="slab" if slab else "rows")
+                    sp.set_attrs(bytes=len(out[-1]), ahead=ahead,
+                                 layout="rows" if idx is None else "slab")
+                # the issued copy and its host array go before the next
+                # partition, so at most the window is live
+                del arr, host
+                live.subtract(data.devices())
+                n_ahead += ahead
+            msp.set_attrs(ahead=n_ahead)
         return out
+
+    def _issue_copy(self, data: jax.Array, idx) -> jax.Array:
+        """``data`` (rows; ``idx`` None) or its slot ``idx`` packed as a
+        slab, with its copy to the host started."""
+        ids = sorted(d.id for d in data.devices())
+        with self.tracer.span("d2h-issue", track="output", attrs={
+                "device": ids[0] if len(ids) == 1 else ids}):
+            arr = data if idx is None else _slab(data, idx)
+            arr.copy_to_host_async()
+        return arr
 
 
 def make_executor(backend: str, client, workers: Sequence[str], *,

@@ -7,6 +7,8 @@ bytes reference and the device-resident array executor.  These tests
 exercise the paths PR 1 only covered via bytes (stragglers, dead-worker
 retries) on the array backend, and pin the planner-purity guarantee by
 diffing SphereReports across backends."""
+import weakref
+
 import numpy as np
 import pytest
 
@@ -15,6 +17,7 @@ import jax.numpy as jnp
 
 from conftest import make_cloud
 from repro.core import SphereEngine, SphereJob, SphereStage, Tracer
+from repro.core import executor
 from repro.core.executor import (SLAB_MIN_BYTES, ArrayExecutor, _SlotRef,
                                  _as_batch, _slab)
 from repro.core.records import RecordBatch, StackedBatch
@@ -458,17 +461,38 @@ def test_pad_unstable_udf_is_rejected(tmp_path):
 
 # ------------------------- output copy-out as a slab ------------------------
 
-def _junk_stack(width):
-    """Three slots whose padded rows fill just over SLAB_MIN_BYTES, every
-    byte non-zero, padded tails included."""
+def _junk_stack(width, n_valid=None):
+    """Slots (three unless ``n_valid`` gives their counts) whose padded
+    rows fill just over SLAB_MIN_BYTES, every byte non-zero, padded
+    tails included."""
     block = -(-SLAB_MIN_BYTES // width) + 17
+    if n_valid is None:
+        n_valid = [block - 5, 7, block // 2]
     data = np.random.default_rng(width).integers(
-        1, 256, (3, block, width), dtype=np.uint8)
-    return StackedBatch(jnp.asarray(data),
-                        np.array([block - 5, 7, block // 2], np.int32))
+        1, 256, (len(n_valid), block, width), dtype=np.uint8)
+    return StackedBatch(jnp.asarray(data), np.array(n_valid, np.int32))
 
 
-@pytest.mark.parametrize("kind", ["slot", "concat"])
+def _copy_out_parts(kind, st):
+    """``(parts, layouts)``: a worker ring of ``kind`` over ``st`` and the
+    layout each non-empty partition crosses in."""
+    if kind == "slot":
+        return ({f"w{i}": _SlotRef(st, i) for i in range(st.n_slots)},
+                ["slab"] * st.n_slots)
+    concat = RecordBatch.concat([st.slot(0), st.slot(1)])
+    if kind == "concat":  # a worker that owns several slots
+        return {"w0": concat}, ["rows"]
+    # mixed: slab slots, a small slot and a concatenated batch as rows,
+    # an empty slot and a missing partition, in one worker ring
+    small = StackedBatch(jnp.asarray(np.random.default_rng(2).integers(
+        1, 256, (2, 64, st.record_size), np.uint8)), np.array([40, 0]))
+    return ({"w0": _SlotRef(st, 0), "w1": None, "w2": concat,
+             "w3": _SlotRef(small, 1), "w4": _SlotRef(st, 1),
+             "w5": _SlotRef(small, 0), "w6": _SlotRef(st, 2)},
+            ["slab", "rows", "slab", "rows", "slab"])
+
+
+@pytest.mark.parametrize("kind", ["slot", "concat", "mixed"])
 @pytest.mark.parametrize("width", [100, 80, 13])
 def test_slab_copy_out_matches_rows(width, kind):
     """A stack slot above the threshold crosses as a uint32 slab, and its
@@ -476,20 +500,50 @@ def test_slab_copy_out_matches_rows(width, kind):
     the slab's own zero padding never leak (width 13 makes a padded slot
     that is not a whole number of 512-byte slab rows).  A worker that
     owns several slots arrives as a concatenated batch and crosses as
-    its rows, with the same bytes."""
+    its rows, with the same bytes.  Issued ahead, every copy but the
+    first starts before the previous partition's ``tobytes``, and the
+    outputs keep worker order, empty partitions left out."""
     st = _junk_stack(width)
-    if kind == "slot":
-        parts = {f"w{i}": _SlotRef(st, i) for i in range(st.n_slots)}
-    else:  # a worker that owns several slots
-        parts = {"w0": RecordBatch.concat([st.slot(0), st.slot(1)])}
+    parts, layouts = _copy_out_parts(kind, st)
     want = [np.asarray(_as_batch(p).data)[:p.num_records].tobytes()
-            for p in parts.values()]
+            for p in parts.values() if p is not None and p.num_records]
     tracer = Tracer()
     got = ArrayExecutor(None, list(parts), tracer=tracer).outputs(parts)
     assert got == want
-    layouts = [e.attrs["layout"] for e in tracer.snapshot()
-               if e.name == "d2h"]
-    assert layouts == ["slab" if kind == "slot" else "rows"] * len(parts)
+    snap = tracer.snapshot()
+    d2h = [e.attrs for e in snap if e.name == "d2h"]
+    assert [a["layout"] for a in d2h] == layouts
+    assert [a["ahead"] for a in d2h] == [False] + [True] * (len(d2h) - 1)
+    assert [e.attrs["ahead"] for e in snap
+            if e.name == "materialise"] == [len(d2h) - 1]
+    issue = [e for e in snap if e.name == "d2h-issue"]
+    tobytes = [e for e in snap if e.name == "d2h-tobytes"]
+    assert len(issue) == len(tobytes) == len(d2h)
+    assert all(issue[k + 1].t0 < tobytes[k].t0
+               for k in range(len(tobytes) - 1))
+
+
+def test_copy_out_holds_two_issued_slabs_per_device(monkeypatch):
+    """The copy-out's window: a device never holds more than two issued
+    slabs, the one being copied and the next, and each leaves once its
+    bytes are made."""
+    live, most = [], []
+
+    def counted(data, idx):
+        arr = slab(data, idx)
+        live.append(weakref.ref(arr))
+        most.append(sum(r() is not None for r in live))
+        return arr
+
+    slab = executor._slab
+    monkeypatch.setattr(executor, "_slab", counted)
+    st = _junk_stack(100, [5000, 1, 9000, 300, 10485, 2])
+    parts = {f"w{i}": _SlotRef(st, i) for i in range(st.n_slots)}
+    got = ArrayExecutor(None, list(parts)).outputs(parts)
+    host = np.asarray(st.data)
+    assert got == [host[i, :n].tobytes() for i, n in enumerate(st.n_valid)]
+    assert most == [1, 2, 2, 2, 2, 2]
+    assert all(r() is None for r in live)
 
 
 def test_slab_compiles_once_per_stack_shape():

@@ -143,11 +143,24 @@ def test_stage0_puts_each_chunk_on_its_slots_device():
 
 def test_sharded_slots_leave_as_slabs():
     """The slots of a stack sharded over the mesh each cross as a slab
-    of the one-device shard that holds them, byte for byte the rows."""
+    of the one-device shard that holds them, byte for byte the rows.
+    Two slots a device fit the copy-out's window, so every copy is
+    issued before the first ``tobytes`` ends, and no device ever holds
+    more than two issued slabs."""
     out = run_py("""
+        import weakref
         import jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.core.records import StackedBatch
+        live, most = [], collections.Counter()
+        def counted(data, idx):
+            arr = slab(data, idx)
+            dev = next(iter(arr.devices())).id
+            live.append((dev, weakref.ref(arr)))
+            most[dev] = max(most[dev], sum(
+                d == dev and r() is not None for d, r in live))
+            return arr
+        slab, ex._slab = ex._slab, counted
         mesh = data_mesh(4)
         rows = 16384                       # 1.6 MB a slot: over SLAB_MIN_BYTES
         host = np.random.default_rng(3).integers(
@@ -163,8 +176,17 @@ def test_sharded_slots_leave_as_slabs():
         outs = plane.outputs(parts)
         want = [host[i, :n].tobytes() for i, n in enumerate(n_valid) if n]
         assert outs == want
-        d2h = spans(tracer.snapshot(), 'd2h')
+        snap = tracer.snapshot()
+        d2h = spans(snap, 'd2h')
         assert [a['layout'] for a in d2h] == ['slab'] * 7, d2h
+        assert [a['ahead'] for a in d2h] == [False] + [True] * 6, d2h
+        assert spans(snap, 'materialise') == [{'ahead': 6}]
+        issue = [s for s in snap if s.name == 'd2h-issue']
+        first = min(s.t1 for s in snap if s.name == 'd2h-tobytes')
+        assert len(issue) == 7 and all(s.t0 < first for s in issue)
+        assert [s.attrs['device'] for s in issue] == [0, 0, 1, 2, 2, 3, 3]
+        assert dict(most) == {0: 2, 1: 1, 2: 2, 3: 2}, most
+        assert all(r() is None for _, r in live)
         print('OK')
     """)
     assert "OK" in out

@@ -238,8 +238,8 @@ def test_report_equals_registry_array(tmp_path):
                              **labels) == traces
 
 
-ARRAY_ONLY = {"host-sync", "h2d-put", "output-wait", "d2h", "d2h-transfer",
-              "d2h-tobytes"}
+ARRAY_ONLY = {"host-sync", "h2d-put", "output-wait", "d2h", "d2h-issue",
+              "d2h-transfer", "d2h-tobytes"}
 
 
 def _shared_span_counts(tracer):
