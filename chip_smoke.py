@@ -9,7 +9,7 @@ width, and checks every output against a plain numpy reference:
   through the partition stage, the fused shuffle round (``bucket_scatter``
   kernel) and the sort stage.  The output must equal a stable numpy sort
   of the same records byte for byte, every shuffle round must have run on
-  the compiled lowering, and each round must cost one host sync.
+  the fused path, and each round must cost one host sync.
 * **kmeans** — float32 points (DIM=8, K=10) through a ``SphereSession``
   chain over the ``kmeans_assign`` kernel.  The centroids must match a
   numpy Lloyd's loop from the same init, and each stage UDF must have
@@ -172,8 +172,7 @@ def run_terasort(n_records: int, seed: int, root: str, *, n_servers: int,
 
     got = np.frombuffer(b"".join(outputs), np.uint8).reshape(-1, RECORD)
     spans = tracer.snapshot()
-    rounds = [(s.attrs.get("path"), s.attrs.get("lowering"))
-              for s in spans if s.name == "shuffle-round"]
+    rounds = [s.attrs.get("path") for s in spans if s.name == "shuffle-round"]
     # where the wall time went, per job phase (compiles included: the
     # first dispatch of each program compiles synchronously)
     phases = {}
@@ -189,7 +188,7 @@ def run_terasort(n_records: int, seed: int, root: str, *, n_servers: int,
              f"terasort output shape {got.shape} != {rec.shape}")
     _require(np.array_equal(got, stable_key_sort(rec)),
              "terasort output differs from the stable numpy sort")
-    want = ("mesh", None) if mesh is not None else ("fused", "vmapped")
+    want = "mesh" if mesh is not None else "fused"
     _require(bool(rounds) and all(r == want for r in rounds),
              f"shuffle rounds took {rounds}, expected all {want}")
     # a mesh round redone at a larger exchange capacity syncs once more

@@ -360,8 +360,7 @@ class _TracedUDF:
         """Tuple of per-task 2-D pieces (stage-0 decoded chunks) stacked
         INSIDE the trace: each piece pads/slices to ``target`` rows and
         one ``jnp.stack`` forms the [s, target, width] block — no eager
-        per-piece dispatch, mirroring _scatter_dest_segments' in-jit
-        stack rationale.  Not concatenate+reshape: for uint8 [rows, 100]
+        per-piece dispatch.  Not concatenate+reshape: for uint8 [rows, 100]
         pieces the TPU compiler takes minutes over that form (PERF.md,
         "Where the time goes")."""
         blocks = []
@@ -632,8 +631,8 @@ class ArrayExecutor(_ExecutorBase):
         pad_block.  This shape is computed once per stage, so the finer
         ladder costs no extra traces while capping the junk-tail of
         resident pieces at ~25% worst case — typically a few percent —
-        junk the segmented scatter would otherwise mask, scan and fetch
-        every round (a pure power-of-two ceiling wastes up to ~100%).
+        junk the round's scatter would otherwise scan and move every
+        round (a pure power-of-two ceiling wastes up to ~100%).
         Row counts come from the plan's task sizes / resident
         partitions, so no batch has to be fetched (or held) to compute
         it."""
@@ -929,7 +928,6 @@ class ArrayExecutor(_ExecutorBase):
         rep.shuffle_rounds += 1
         with self.tracer.span("shuffle-round", track="shuffle",
                               attrs={"backend": "array", "path": "fused",
-                                     "lowering": rd.mode,
                                      "buckets": n}) as sp:
             rep.device_dispatches += rd.dispatches
             synced = jax.device_get(rd.sync_arrays)  # the round's ONE sync
@@ -940,11 +938,8 @@ class ArrayExecutor(_ExecutorBase):
             result = rd.harvest(synced)
             rep.device_dispatches += result.dispatches
             rep.partitioned_records += out.stacked.num_records
-            if self.timing_sync:
-                if result.data is not None:
-                    jax.block_until_ready(result.data)
-                elif result.groups:
-                    jax.block_until_ready([g for _, g in result.groups])
+            if self.timing_sync and result.data is not None:
+                jax.block_until_ready(result.data)
         rep.partition_seconds += sp.wall_seconds
         return result, result.origins
 
@@ -1055,18 +1050,6 @@ class ArrayExecutor(_ExecutorBase):
             # stacked result IS worker i's merged partition — parts hold
             # zero-copy views into the stack, so chained stages restack
             # for free (see _aligned_stacked)
-            if buckets.groups is not None:
-                # big rounds arrive as a few worker-contiguous group
-                # stacks (gather rows per call are capped, see
-                # FusedRoundResult.groups); every worker still gets a
-                # zero-copy view into its group's stack
-                for w0, arr in buckets.groups:
-                    g = StackedBatch(arr,
-                                     buckets.counts[w0:w0 + arr.shape[0]])
-                    for j in range(arr.shape[0]):
-                        parts[self.workers[w0 + j]] = (
-                            _SlotRef(g, j) if int(g.n_valid[j]) else None)
-                return
             if buckets.data is None:
                 for w in self.workers:
                     parts[w] = None

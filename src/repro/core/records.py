@@ -360,8 +360,8 @@ def _quarter_rows(n: int, floor: int) -> int:
     denser ladder costs no extra traces there — and it caps the
     junk-tail at ~25% worst case (typically a few percent) where the
     half-octave ladder allows ~33%.  That junk tail is not free: every
-    padding row rides through the segmented scatter's mask, kernel scan
-    and destination fetch each round (e.g. 5 000-record stage-0 chunks
+    padding row rides through the round's kernel scan and row movement
+    each round (e.g. 5 000-record stage-0 chunks
     pad to 5 120 here vs 6 144 on the half-octave ladder — an 18%
     shuffle-volume cut at the TeraSort 1M scale).  Ad-hoc batch padding
     (``scatter_batch``) keeps the coarser ladder, where fewer rungs

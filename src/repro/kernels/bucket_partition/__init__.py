@@ -1,5 +1,4 @@
-from repro.kernels.bucket_partition.ops import (bucket_dest,  # noqa: F401
-                                                bucket_partition,
+from repro.kernels.bucket_partition.ops import (bucket_partition,  # noqa: F401
                                                 bucket_scatter)
 from repro.kernels.bucket_partition.ref import (bucket_partition_ref,  # noqa: F401
                                                 bucket_scatter_ref)

@@ -243,12 +243,9 @@ def bucket_dest_call(keys: jax.Array, bounds: jax.Array, n_valid, *,
     """Destination indices + histogram of the stable counting scatter.
 
     ``keys``: [N] or [N, k] uint32 key rows; ``bounds``: [n_bounds] or
-    [n_bounds, k] sorted boundary rows; ``n_valid``: either a dynamic
-    scalar (the leading ``n_valid`` rows are real, the rest shape
-    padding) or a dynamic [N] int32/bool mask marking real rows
-    anywhere in the batch (stacked resident pieces keep their junk
-    tails in place) — masked-out rows go to the trash bucket after
-    every real bucket either way.
+    [n_bounds, k] sorted boundary rows; ``n_valid``: a dynamic scalar —
+    the leading ``n_valid`` rows are real, the rest shape padding, which
+    goes to the trash bucket after every real bucket.
 
     Returns ``(dest [Np] int32, hist [n_out] int32)`` where ``Np`` is
     ``N`` rounded up to a multiple of the grid block (``block_n``, itself
@@ -260,29 +257,18 @@ def bucket_dest_call(keys: jax.Array, bounds: jax.Array, n_valid, *,
     intra-block rank`` — the classic three-level exclusive-scan scatter,
     with the two outer scans (over buckets and over blocks) done by the
     XLA epilogue on the kernel's per-block histograms.  This is the
-    data-free half of :func:`bucket_scatter_call`; callers that can move
-    the rows more cheaply themselves (e.g. a host-side permutation
-    inversion on CPU) stop here.
+    data-free half of :func:`bucket_scatter_call`.
     """
     kt, bt = _lane_major(keys, bounds)
     k, N = kt.shape
     bn = _block_rows(block_n, N, _LANES)
     pad = (-N) % bn
-    if pad:  # masked-out rows are trash-bucketed, so padding is benign
+    if pad:  # rows past n_valid are trash-bucketed, so padding is benign
         kt = jnp.pad(kt, ((0, 0), (0, pad)))
     Np = kt.shape[1]
     nb = Np // bn
-    nv = jnp.asarray(n_valid)
-    if nv.ndim == 0:       # scalar count -> prefix-validity mask
-        valid = (jax.lax.iota(jnp.int32, Np)
-                 < nv.astype(jnp.int32)).astype(jnp.int32)
-    else:
-        if nv.shape[0] != N:
-            raise ValueError(f"validity mask has {nv.shape[0]} rows but "
-                             f"keys have {N}")
-        valid = nv.astype(jnp.int32)
-        if pad:
-            valid = jnp.pad(valid, (0, pad))
+    valid = (jax.lax.iota(jnp.int32, Np)
+             < jnp.asarray(n_valid).astype(jnp.int32)).astype(jnp.int32)
 
     kern = functools.partial(_scatter_kernel, n_out=n_out)
     ids, rank, bhist = pl.pallas_call(

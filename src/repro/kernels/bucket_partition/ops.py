@@ -23,8 +23,7 @@ from functools import partial
 
 import jax
 
-from repro.kernels.bucket_partition.kernel import (bucket_dest_call,
-                                                   bucket_partition_call,
+from repro.kernels.bucket_partition.kernel import (bucket_partition_call,
                                                    bucket_scatter_call)
 from repro.utils.backend import pallas_interpret
 
@@ -68,24 +67,3 @@ def bucket_scatter(data, keys, bounds, n_valid, *, n_buckets: int,
     return bucket_scatter_call(data, keys, bounds, n_valid,
                                n_out=n_buckets, block_n=block_n,
                                interpret=interpret)
-
-
-@partial(jax.jit, static_argnames=("n_buckets", "block_n", "interpret"))
-def bucket_dest(keys, bounds, n_valid, *, n_buckets: int,
-                block_n: int | None = None,
-                interpret: bool | None = None):
-    """Scatter destinations without moving any data.
-
-    Returns ``(dest [Np] int32, hist [n_buckets] int32)`` — the stable
-    bucket-contiguous output position of every key row, padded rows
-    included (see :func:`bucket_dest_call`).  For callers that invert
-    the permutation and move rows themselves — on CPU a host-side numpy
-    inversion runs at memcpy speed where XLA's [Np] scatter crawls at
-    ~40ns/element, which is why the CPU shuffle path stops here.
-    """
-    if interpret is None:
-        interpret = pallas_interpret()
-    if block_n is None:
-        block_n = keys.shape[0] if interpret else ACCEL_BLOCK_N
-    return bucket_dest_call(keys, bounds, n_valid, n_out=n_buckets,
-                            block_n=block_n, interpret=interpret)

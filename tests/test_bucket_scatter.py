@@ -220,17 +220,15 @@ def _resident_pieces(rec, counts, rows, seed=0):
 
 
 def test_scatter_pieces_segmented_parity():
-    """Uniform resident pieces take the fused segmented path — no eager
-    concat, host-invert metadata pending — and harvest exactly the
-    buckets the bytes backend builds from the pieces' valid records in
-    piece order."""
+    """Uniform resident pieces dispatch as one pending scatter — no host
+    sync at dispatch — and harvest exactly the buckets the bytes backend
+    builds from the pieces' valid records in piece order."""
     rec, nb, rows = 16, 5, 96
     pieces, records = _resident_pieces(rec, [60, 11, 90, 1], rows, seed=41)
     part = range_partitioner(sample_boundaries(records, nb, key_bytes=10))
     disp = scatter_pieces_dispatch(pieces, part, nb, pad_block=PAD,
                                    interpret=True)
     assert disp.pending and disp.host_syncs == 0
-    assert disp.src is not None and disp.dest is not None
     out = disp.harvest()
     want = [[] for _ in range(nb)]
     for r in records:

@@ -388,12 +388,11 @@ def test_fused_rounds_match_unfused_and_bytes(tmp_path):
 
 
 def test_fused_dispatches_constant_in_workers_and_tasks(tmp_path):
-    """The tentpole invariant: a fused round costs O(1) compiled
-    dispatches — one stacked UDF call, a bounded shard fan of scatter
-    calls, one regrouping gather — regardless of worker count or task
-    count, where the per-task/per-worker loop grows linearly."""
-    from repro.core.shuffle import _ROUND_MAX_SHARDS
-
+    """The tentpole invariant: a fused job costs a fixed count of
+    compiled dispatches — the stacked partition-stage apply, the stacked
+    scatter, the one regrouping gather and the stacked sort stage —
+    regardless of worker count or task count, where the per-task /
+    per-worker loop grows linearly."""
     def run(n_servers, n_records, fused):
         sub = tmp_path / f"{n_servers}-{n_records}-{fused}"
         sub.mkdir()
@@ -404,21 +403,16 @@ def test_fused_dispatches_constant_in_workers_and_tasks(tmp_path):
         _, rep = eng.run(_terasort_job("array", data))
         return rep
 
-    # ceiling: stacked apply + shard fan + harvest gather + next stage
-    cap = _ROUND_MAX_SHARDS + 4
     small = run(2, 100, True)
     wide = run(6, 100, True)
     many = run(6, 400, True)     # 4x the tasks
     for rep in (small, wide, many):
-        assert 0 < rep.device_dispatches <= cap
+        assert rep.device_dispatches == 4
         assert rep.shuffle_rounds == 1
-    assert wide.device_dispatches == small.device_dispatches
-    assert many.device_dispatches <= small.device_dispatches + \
-        _ROUND_MAX_SHARDS - 1    # shard fan may widen, nothing else may
     # the per-task loop's count grows with tasks (the contrast the
     # fused invariant is measured against)
     loopy = run(6, 400, False)
-    assert loopy.device_dispatches > cap
+    assert loopy.device_dispatches > 4
 
 
 def test_prefetch_depth_reports_bit_identical(tmp_path):

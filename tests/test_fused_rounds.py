@@ -6,7 +6,7 @@ because it agrees with the reference record-for-record: for every bucket,
 the same records in the same order (slot-major, input order within a
 slot), regrouped onto the same destination workers, with identical
 origin-byte accounting.  These tests drive :func:`scatter_round_dispatch`
-(both lowerings) and the shard_map twin ``spmd.fused_scatter_round`` over
+and the shard_map twin ``spmd.fused_scatter_round`` over
 ragged rounds — empty slots, empty workers, boundary-colliding keys —
 against a per-record Python reference, plus a hypothesis property test
 over ragged loads when hypothesis is installed.
@@ -76,19 +76,14 @@ def _assert_round_parity(slots, slot_workers, worker_names, part, n, rec,
         slots, slot_workers, worker_names, part, n)
     assert result.counts.tolist() == want_counts
     assert result.origins == want_origins
-    if result.groups is not None:
-        for w0, arr in result.groups:
-            g = np.asarray(arr)
-            for j in range(g.shape[0]):
-                w = w0 + j
-                assert g[j, :want_counts[w]].tobytes() == want_parts[w]
-        return
     if result.data is None:
         assert sum(want_counts) == 0
-        return
+        return result
     got = np.asarray(result.data)
+    assert got.shape[:1] == (len(worker_names),)
     for w in range(len(worker_names)):
         assert got[w, :want_counts[w]].tobytes() == want_parts[w]
+    return result
 
 
 WORKERS = [f"s{i}" for i in range(4)]
@@ -118,21 +113,20 @@ def test_stacked_round_matches_reference(loads, n_buckets, which):
 
 
 def test_vmapped_lowering_matches_segmented():
-    """Both lowerings of the stacked round — the CPU segmented-shard
-    path and the single vmapped scatter the compiled backends take —
-    must produce identical regrouped partitions and origins."""
+    """A ragged hash round (an empty slot, more slots than workers, a
+    short-key hash) through the one vmapped scatter matches the
+    reference: regrouped partitions, counts and origins."""
     rec, n = 16, 5
     slots = _ragged_round([11, 0, 6, 23, 2, 9], rec, seed=3)
     slot_workers = np.sort(np.arange(6) % len(WORKERS))
     part = hash_partitioner(key_bytes=4)
-    for lowering in ("segmented", "vmapped"):
-        _assert_round_parity(slots, slot_workers, WORKERS, part, n, rec,
-                             lowering=lowering, interpret=True)
+    _assert_round_parity(slots, slot_workers, WORKERS, part, n, rec,
+                         interpret=True)
 
 
 def test_round_dispatch_is_o1_in_slots():
-    """The per-round dispatch count is bounded (shard cap + harvest
-    gather), regardless of how many slots the round stacks."""
+    """A round costs exactly two dispatches — the stacked scatter and
+    the harvest's regroup gather — however many slots it stacks."""
     rec = 8
     part = hash_partitioner(key_bytes=4)
     disp = []
@@ -146,37 +140,22 @@ def test_round_dispatch_is_o1_in_slots():
                                     pad_block=8)
         result = rd.harvest()
         disp.append(rd.dispatches + result.dispatches)
-    from repro.core.shuffle import _ROUND_MAX_SHARDS
-    assert max(disp) <= _ROUND_MAX_SHARDS + 3
-    assert disp[-1] <= disp[0] + _ROUND_MAX_SHARDS  # no per-slot growth
+    assert disp == [2, 2, 2]
 
 
-def test_grouped_harvest_matches_reference(monkeypatch):
-    """Rounds past ``_ROUND_SHARD_ROWS`` split the regroup gather into
-    worker-contiguous group takes; shrink the threshold to force that
-    path at test scale and check record-for-record parity."""
-    from repro.core import shuffle as sh
-    monkeypatch.setattr(sh, "_ROUND_SHARD_ROWS", 16)
-    rec, n = 12, 8
-    slots = _ragged_round([9, 17, 4, 0, 22, 6], rec, seed=13)
-    slot_workers = np.sort(np.arange(6) % len(WORKERS))
+def test_grouped_harvest_matches_reference():
+    """A many-worker round regroups in ONE gather: every destination
+    worker's partition is a slot of the one [W, block2, width] stack,
+    record-for-record the reference's."""
+    rec, n = 12, 16
+    workers = [f"s{i}" for i in range(8)]
+    slots = _ragged_round([9, 17, 4, 0, 22, 6, 13, 1, 30, 5], rec, seed=13)
+    slot_workers = np.sort(np.arange(10) % len(workers))
     part = hash_partitioner(key_bytes=8)
-    stacked = _pack(slots, rec)
-    rd = sh.scatter_round_dispatch(stacked, part, n,
-                                   worker_names=WORKERS,
-                                   slot_workers=slot_workers, pad_block=8)
-    assert rd is not None
-    result = rd.harvest()
-    assert result.groups is not None and len(result.groups) > 1
-    want_parts, want_counts, want_origins = _reference(
-        slots, slot_workers, WORKERS, part, n)
-    assert result.counts.tolist() == want_counts
-    assert result.origins == want_origins
-    for w0, arr in result.groups:
-        g = np.asarray(arr)
-        for j in range(g.shape[0]):
-            w = w0 + j
-            assert g[j, :want_counts[w]].tobytes() == want_parts[w]
+    result = _assert_round_parity(slots, slot_workers, workers, part, n,
+                                  rec)
+    assert result.dispatches == 1
+    assert result.data.shape[2] == rec
 
 
 def test_ineligible_rounds_return_none():
@@ -204,7 +183,7 @@ def test_vmapped_round_compiles_on_accelerator():
     part = range_partitioner(
         sample_boundaries([r for s in slots for r in s], n, key_bytes=10))
     _assert_round_parity(slots, slot_workers, WORKERS, part, n, rec,
-                         lowering="vmapped", interpret=False)
+                         interpret=False)
 
 
 def test_mesh_fused_round_matches_host_harvest():

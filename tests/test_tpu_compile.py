@@ -234,8 +234,8 @@ def test_stacked_round_kernel_matches_the_roofline_reader(one_chip):
 
 @pytest.mark.parametrize("stage", ["partition", "sort"])
 def test_terasort_stage_modules_carry_the_stage_name(one_chip, stage):
-    """Both TeraSort stages run through the stacked-pieces entry point;
-    their modules differ by the stage's name, which the device trace
+    """Both TeraSort stages compiled through the stacked-pieces entry
+    point: their modules differ by the stage's name, which the device trace
     keeps (``sort_device_s`` reads ``jit_stage_sort_*``)."""
     from repro.core.shuffle import terasort_stages
     st = {s.name: s for s in terasort_stages([], "array", 1)}[stage]
